@@ -445,8 +445,9 @@ def run_tp_scaling(emit):
     the guards are structural, not wall-clock: every tp must keep the
     steady step at exactly 1.0 dispatches/step (a shard_map-wrapped jit
     is still one launch) and produce token-for-token identical outputs
-    (head-parallel qkv + tiled all_gather splits no contraction, so
-    per-device math is bitwise the single-device math)."""
+    (replicated params + head-parallel attention + tiled all_gather
+    split no contraction, so per-device math is bitwise the
+    single-device math)."""
     import json
     import subprocess
     import sys

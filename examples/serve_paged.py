@@ -18,6 +18,7 @@ from repro.core.attention import heuristics
 from repro.models import model as M
 from repro.serving.engine import Engine
 from repro.serving.request import make_requests
+from repro.utils.compile_cache import enable_compile_cache
 
 
 def main():
@@ -103,6 +104,7 @@ def main():
                          "steps (artifacts land in --metrics-dir or cwd)")
     args = ap.parse_args()
 
+    print(f"compile cache: {enable_compile_cache()}")
     cfg = reduced(ARCHS[args.arch]).replace(dtype="float32")
     params = M.init(cfg, jax.random.key(0))
 

@@ -55,7 +55,8 @@ QWEN25_3B = register(ModelConfig(
     name="qwen2.5-3b", family="dense",
     num_layers=36, d_model=2048, num_q_heads=16, num_kv_heads=2,
     d_ff=11008, vocab_size=151936, qkv_bias=True, rope_theta=1000000.0,
-    source="hf:Qwen/Qwen2.5-0.5B; hf",
+    norm_eps=1e-6, tie_embeddings=True,
+    source="hf:Qwen/Qwen2.5-3B; hf",
 ))
 
 # --- MoE [hf:meta-llama/Llama-4-Scout-17B-16E] ------------------------------

@@ -145,49 +145,6 @@ def assign_cache_shardings(abstract_cache, *, mesh: Mesh,
     return jax.tree_util.tree_unflatten(treedef, out)
 
 
-# ---------------------------------------------------------------------------
-# Serving tensor parallelism (the mesh executor, docs/serving.md)
-# ---------------------------------------------------------------------------
-
-# Projections whose OUTPUT dim is whole attention heads. These are the ONLY
-# params the serving executor shards: each device computes its own head
-# block end to end (column-parallel, no comm), the KV pages split on the
-# same head axis, and one all-gather of attention outputs reassembles the
-# full head set before the replicated `wo`. No contraction is ever split,
-# so per-device math is BIT-IDENTICAL to the single-device program — the
-# property the tp differential tests pin. Fused `wqkv` stays replicated
-# (its output interleaves q|k|v, so a contiguous split would not land on
-# head boundaries); the attention layer slices local heads post-projection.
-SERVE_HEAD_PARALLEL = ("wq", "wk", "wv")
-
-
-def serve_param_spec(path: str, leaf, *, tp: int, axis: str = "tp") -> P:
-    if tp == 1 or getattr(leaf, "ndim", 0) < 1:
-        return P()
-    if any(f"'{n}'" in path for n in SERVE_HEAD_PARALLEL) \
-            and leaf.shape[-1] % tp == 0:
-        spec = [None] * leaf.ndim
-        spec[-1] = axis  # output (head) dim: w [d, H*dh], b [H*dh]
-        return P(*spec)
-    return P()
-
-
-def serve_param_specs(params, *, tp: int, axis: str = "tp"):
-    """Pytree of PartitionSpecs mirroring `params` (shard_map in_specs)."""
-    flat, treedef = jax.tree_util.tree_flatten_with_path(params)
-    out = [serve_param_spec(jax.tree_util.keystr(p), leaf, tp=tp, axis=axis)
-           for p, leaf in flat]
-    return jax.tree_util.tree_unflatten(treedef, out)
-
-
-def assign_serve_param_shardings(params, *, mesh: Mesh, axis: str = "tp"):
-    tp = mesh.shape[axis]
-    return jax.tree_util.tree_map(
-        lambda s: NamedSharding(mesh, s),
-        serve_param_specs(params, tp=tp, axis=axis),
-        is_leaf=lambda x: isinstance(x, P))
-
-
 def batch_spec(key: str, leaf, *, mesh: Mesh,
                batch_axes: tuple[str, ...]) -> P:
     data_n = _axes_size(mesh, batch_axes)

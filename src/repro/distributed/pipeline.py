@@ -16,7 +16,6 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 
 def pipeline_apply(
@@ -58,13 +57,9 @@ def pipeline_apply(
             return (nxt, outs), None
 
         # the carry becomes device-varying after ppermute; mark it as such
-        # (jax<0.7 has no pcast/varying-axes tracking — plain zeros suffice)
-        pcast = getattr(jax.lax, "pcast", None)
-        buf0 = jnp.zeros_like(x_local[0])
-        outs0 = jnp.zeros_like(x_local)
-        if pcast is not None:
-            buf0 = pcast(buf0, (axis,), to="varying")
-            outs0 = pcast(outs0, (axis,), to="varying")
+        buf0 = jax.lax.pcast(jnp.zeros_like(x_local[0]), (axis,),
+                             to="varying")
+        outs0 = jax.lax.pcast(jnp.zeros_like(x_local), (axis,), to="varying")
         (_, outs), _ = jax.lax.scan(
             tick, (buf0, outs0), jnp.arange(ticks)
         )
@@ -72,7 +67,7 @@ def pipeline_apply(
         return jax.lax.psum(outs, axis)
 
     pspec = jax.tree.map(lambda _: P(axis), stage_params)
-    return shard_map(
+    return jax.shard_map(
         shard_body,
         mesh=mesh,
         in_specs=(jax.tree.map(lambda _: P(axis), stage_params), P()),

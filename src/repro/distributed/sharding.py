@@ -17,23 +17,19 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
-import inspect
 import threading
 
 import jax
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
-# jax moved shard_map out of experimental (and renamed the replication-
-# check kwarg check_rep -> check_vma) across the versions we support
-if hasattr(jax, "shard_map"):
-    shard_map = jax.shard_map
-else:  # pragma: no cover - depends on installed jax
-    from jax.experimental.shard_map import shard_map
-SHARD_MAP_NOCHECK = (
-    {"check_vma": False}
-    if "check_vma" in inspect.signature(shard_map).parameters
-    else {"check_rep": False}
-)
+
+def make_mesh(shape, axes) -> Mesh:
+    """`jax.make_mesh` over the first prod(shape) devices with every axis
+    `Auto`: jax.make_mesh defaults to `Explicit` axes, which
+    `with_sharding_constraint` (`constrain`) and the GSPMD-partitioned
+    jits here do not accept."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(shape))
 
 
 @dataclasses.dataclass(frozen=True)

@@ -12,3 +12,18 @@ the pure-jnp oracles in ref.py.
   mamba2/           chunked SSD scan for hybrid archs (zamba2).
   mlstm/            xLSTM matrix-memory chunkwise kernel.
 """
+from __future__ import annotations
+
+import jax
+
+
+def interpret_mode(interpret: bool | None = None) -> bool:
+    """Whether a kernel call runs in Pallas interpret mode: an explicit
+    `interpret` wins; otherwise kernels compile natively on a TPU backend
+    and are interpreted everywhere else (the CPU test suite).  The ops
+    modules call this through the package attribute, so a test that
+    compiles for a described TPU while the backend is still the CPU can
+    patch it."""
+    if interpret is not None:
+        return interpret
+    return jax.default_backend() != "tpu"

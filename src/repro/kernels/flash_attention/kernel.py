@@ -16,9 +16,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax<0.5 compat: TPUCompilerParams was renamed CompilerParams upstream
-_CompilerParams = getattr(pltpu, "CompilerParams",
-                          getattr(pltpu, "TPUCompilerParams", None))
 
 _NEG_INF = -0.7 * float(jnp.finfo(jnp.float32).max)
 
@@ -144,7 +141,7 @@ def flash_attention_fwd(
             pltpu.VMEM((bm, 128), jnp.float32),
             pltpu.VMEM((bm, 128), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

@@ -9,17 +9,12 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro import kernels
 from repro.kernels.flash_attention import kernel as K
 from repro.kernels.flash_attention.ref import flash_attention_xla
 from repro.utils.misc import round_up
 
 LANE = 128
-
-
-def _auto_interpret(interpret):
-    if interpret is not None:
-        return interpret
-    return jax.default_backend() != "tpu"
 
 
 @functools.partial(
@@ -101,5 +96,5 @@ def flash_attention(
     assert sq % block_q == 0 and skv % kv_block == 0
     return _flash(
         q, k, v, causal, scale, block_q, kv_block, q_offset,
-        _auto_interpret(interpret),
+        kernels.interpret_mode(interpret),
     )

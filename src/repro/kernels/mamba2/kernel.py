@@ -16,10 +16,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax<0.5 compat: TPUCompilerParams was renamed CompilerParams upstream
-_CompilerParams = getattr(pltpu, "CompilerParams",
-                          getattr(pltpu, "TPUCompilerParams", None))
-
 
 def _dot(a, b, trans_a=False, trans_b=False):
     dn = (((0 if trans_a else 1,), (1 if trans_b else 0,)), ((), ()))
@@ -110,7 +106,7 @@ def ssd_chunked_fwd(
             jax.ShapeDtypeStruct(s0.shape, jnp.float32),
         ),
         scratch_shapes=[pltpu.VMEM((n, p), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

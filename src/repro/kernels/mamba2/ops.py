@@ -11,14 +11,9 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro import kernels
 from repro.kernels.mamba2 import kernel as K
 from repro.kernels.mamba2.ref import ssd_chunked
-
-
-def _auto_interpret(interpret):
-    if interpret is not None:
-        return interpret
-    return jax.default_backend() != "tpu"
 
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
@@ -35,7 +30,7 @@ def mamba2_ssd(
     interpret: bool | None = None,
 ):
     """Pallas-forward chunked SSD. Returns (y [B,L,H,P], final_state)."""
-    interpret = _auto_interpret(interpret)
+    interpret = kernels.interpret_mode(interpret)
     bsz, l, h, p = x.shape
     n = b.shape[-1]
     g = b.shape[2]

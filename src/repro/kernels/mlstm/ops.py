@@ -6,14 +6,9 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro import kernels
 from repro.kernels.mlstm import kernel as K
 from repro.kernels.mlstm.ref import mlstm_chunked
-
-
-def _auto_interpret(interpret):
-    if interpret is not None:
-        return interpret
-    return jax.default_backend() != "tpu"
 
 
 def _logsigmoid(x):
@@ -33,7 +28,7 @@ def mlstm(
     interpret: bool | None = None,
 ):
     """Pallas chunkwise mLSTM. Returns (h [B,L,H,P], (C, n, m) final)."""
-    interpret = _auto_interpret(interpret)
+    interpret = kernels.interpret_mode(interpret)
     bsz, l, h, p = q.shape
     if scale is None:
         scale = p**-0.5

@@ -45,16 +45,19 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax<0.5 compat: TPUCompilerParams was renamed CompilerParams upstream
-_CompilerParams = getattr(pltpu, "CompilerParams",
-                          getattr(pltpu, "TPUCompilerParams", None))
 
 _NEG_INF = -0.7 * float(jnp.finfo(jnp.float32).max)
 
 
 def _dot(a, b, trans_b=False):
     dn = (((1,), (1 if trans_b else 0,)), ((), ()))
-    return jax.lax.dot_general(a, b, dn, preferred_element_type=jnp.float32)
+    # Mosaic's default contraction rounds float32 operands towards bf16 on
+    # a v5e (7e-3 off the float32 reference at Qwen2.5-3B widths); float32
+    # pages get float32 math, bf16 pages keep the default
+    precision = (jax.lax.Precision.HIGHEST
+                 if a.dtype == b.dtype == jnp.float32 else None)
+    return jax.lax.dot_general(a, b, dn, precision=precision,
+                               preferred_element_type=jnp.float32)
 
 
 # ---------------------------------------------------------------------------
@@ -208,7 +211,7 @@ def paged_decode(
             ],
         ),
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -229,8 +232,8 @@ def _decode_segmented_kernel(
     k_ref,  # [1, 1, 1, tile, D]
     v_ref,
     o_ref,  # [1, 1, 1, M, D]   (per segment, unnormalized acc)
-    m_out_ref,  # [1, 1, 1, M]
-    l_out_ref,  # [1, 1, 1, M]
+    m_out_ref,  # [1, 1, 1, M, 128]  (lane-broadcast, like the scratch)
+    l_out_ref,  # [1, 1, 1, M, 128]
     acc_ref,
     m_ref,
     l_ref,
@@ -261,8 +264,8 @@ def _decode_segmented_kernel(
     @pl.when(t == pl.num_programs(3) - 1)
     def _():
         o_ref[0, 0, 0] = acc_ref[...].astype(o_ref.dtype)
-        m_out_ref[0, 0, 0] = m_ref[:, 0]
-        l_out_ref[0, 0, 0] = l_ref[:, 0]
+        m_out_ref[0, 0, 0] = m_ref[...]
+        l_out_ref[0, 0, 0] = l_ref[...]
 
 
 def _make_seg_kv_index_map(tile, tiles_per_page, tiles_per_segment):
@@ -290,7 +293,12 @@ def paged_decode_segmented(
     scale: float,
     interpret: bool = False,
 ):
-    """Returns (o_seg [S,Hkv,nseg,M,D] f32 unnormalized, m_seg, l_seg)."""
+    """Returns (o_seg [S,Hkv,nseg,M,D] f32 unnormalized, m_seg, l_seg).
+
+    m_seg / l_seg are [S, Hkv, nseg, M, 128], every lane holding the same
+    value: Mosaic tiles the last two block dims by (8, 128) unless they
+    span the whole array, so a per-segment [M] row (block (1, 1, 1, M)
+    over [S, Hkv, nseg, M]) would put a 1 in the second-minor dim."""
     s_, hkv, m, d = q.shape
     grid = (s_, hkv, num_segments, tiles_per_segment)
     kernel = functools.partial(
@@ -305,8 +313,8 @@ def paged_decode_segmented(
     )
     out_shapes = (
         jax.ShapeDtypeStruct((s_, hkv, num_segments, m, d), jnp.float32),
-        jax.ShapeDtypeStruct((s_, hkv, num_segments, m), jnp.float32),
-        jax.ShapeDtypeStruct((s_, hkv, num_segments, m), jnp.float32),
+        jax.ShapeDtypeStruct((s_, hkv, num_segments, m, 128), jnp.float32),
+        jax.ShapeDtypeStruct((s_, hkv, num_segments, m, 128), jnp.float32),
     )
     o_seg, m_seg, l_seg = pl.pallas_call(
         kernel,
@@ -322,8 +330,12 @@ def paged_decode_segmented(
                 pl.BlockSpec(
                     (1, 1, 1, m, d), lambda s, h, g, t, pt, cl: (s, h, g, 0, 0)
                 ),
-                pl.BlockSpec((1, 1, 1, m), lambda s, h, g, t, pt, cl: (s, h, g, 0)),
-                pl.BlockSpec((1, 1, 1, m), lambda s, h, g, t, pt, cl: (s, h, g, 0)),
+                pl.BlockSpec(
+                    (1, 1, 1, m, 128), lambda s, h, g, t, pt, cl: (s, h, g, 0, 0)
+                ),
+                pl.BlockSpec(
+                    (1, 1, 1, m, 128), lambda s, h, g, t, pt, cl: (s, h, g, 0, 0)
+                ),
             ),
             scratch_shapes=[
                 pltpu.VMEM((m, d), jnp.float32),
@@ -332,7 +344,7 @@ def paged_decode_segmented(
             ],
         ),
         out_shape=out_shapes,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -344,16 +356,16 @@ def paged_decode_segmented(
 def _segment_reduce_kernel(o_seg_ref, m_seg_ref, l_seg_ref, o_ref):
     """Merge segments (paper Listing 5 `reduce_segments`)."""
     o_seg = o_seg_ref[0, 0]  # [nseg, M, D] f32
-    m_seg = m_seg_ref[0, 0]  # [nseg, M]
-    l_seg = l_seg_ref[0, 0]
-    m_star = jnp.max(m_seg, axis=0, keepdims=True)  # [1, M]
+    m_seg = m_seg_ref[0, 0, :, :, :1]  # [nseg, M, 1] (lane 0)
+    l_seg = l_seg_ref[0, 0, :, :, :1]
+    m_star = jnp.max(m_seg, axis=0, keepdims=True)  # [1, M, 1]
     alive = m_star > _NEG_INF / 2
     m_safe = jnp.where(alive, m_star, 0.0)
-    w = jnp.exp(m_seg - m_safe) * (m_seg > _NEG_INF / 2)  # [nseg, M]
-    l_tot = jnp.sum(l_seg * w, axis=0)  # [M]
-    o_tot = jnp.sum(o_seg * w[:, :, None], axis=0)  # [M, D]
+    w = jnp.exp(m_seg - m_safe) * (m_seg > _NEG_INF / 2)  # [nseg, M, 1]
+    l_tot = jnp.sum(l_seg * w, axis=0)  # [M, 1]
+    o_tot = jnp.sum(o_seg * w, axis=0)  # [M, D]
     l_safe = jnp.where(l_tot == 0.0, 1.0, l_tot)
-    o_ref[0, 0] = (o_tot / l_safe[:, None]).astype(o_ref.dtype)
+    o_ref[0, 0] = (o_tot / l_safe).astype(o_ref.dtype)
 
 
 def segment_reduce(
@@ -370,12 +382,12 @@ def segment_reduce(
         grid=(s_, hkv),
         in_specs=[
             pl.BlockSpec((1, 1, nseg, m, d), lambda s, h: (s, h, 0, 0, 0)),
-            pl.BlockSpec((1, 1, nseg, m), lambda s, h: (s, h, 0, 0)),
-            pl.BlockSpec((1, 1, nseg, m), lambda s, h: (s, h, 0, 0)),
+            pl.BlockSpec((1, 1, nseg, m, 128), lambda s, h: (s, h, 0, 0, 0)),
+            pl.BlockSpec((1, 1, nseg, m, 128), lambda s, h: (s, h, 0, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, 1, m, d), lambda s, h: (s, h, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((s_, hkv, m, d), out_dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel"),
         ),
         interpret=interpret,
@@ -499,7 +511,7 @@ def paged_prefill_qblock(
             ],
         ),
         out_shape=jax.ShapeDtypeStruct(q_packed.shape, q_packed.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

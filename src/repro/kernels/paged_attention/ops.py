@@ -21,18 +21,13 @@ from typing import Literal
 import jax
 import jax.numpy as jnp
 
+from repro import kernels
 from repro.core.paged.kv_cache import ShardingError
 from repro.kernels.paged_attention import kernel as K
 from repro.utils.misc import cdiv, round_up
 
 LANE = 128
 SUBLANE = 8
-
-
-def _auto_interpret(interpret):
-    if interpret is not None:
-        return interpret
-    return jax.default_backend() != "tpu"
 
 
 def _pad_head_dim(x: jax.Array, axis: int = -1) -> jax.Array:
@@ -84,7 +79,7 @@ def paged_attention_decode(
     interpret: bool | None = None,
 ) -> jax.Array:
     """Single-token decode over the paged KV cache. Returns [S, Hq, D]."""
-    interpret = _auto_interpret(interpret)
+    interpret = kernels.interpret_mode(interpret)
     s_, hq, d = q.shape
     hkv, p, ps, dk = k_pages.shape
     assert dk == d
@@ -280,7 +275,7 @@ def paged_attention_prefill(
     The chunk's K/V must already be written to the pages. Returns [T, Hq, D]
     with zeros in dead rows.
     """
-    interpret = _auto_interpret(interpret)
+    interpret = kernels.interpret_mode(interpret)
     t, hq, d = q.shape
     s_ = query_lens.shape[0]
     hkv, p, ps, _ = k_pages.shape

@@ -14,24 +14,14 @@ Straggler/fault notes live in launch/train.py.
 """
 from __future__ import annotations
 
-import jax
+from repro.distributed.sharding import make_mesh
 
 
 def make_production_mesh(*, multi_pod: bool = False):
+    """The first 256 (512) devices, e.g. of 512 forced host devices."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    n = 1
-    for s in shape:
-        n *= s
-    devices = jax.devices()
-    if len(devices) != n:  # e.g. 512 forced host devices, single-pod mesh
-        devices = devices[:n]
-    return jax.make_mesh(shape, axes, devices=devices)
-
-
-def make_test_mesh(shape=(2, 2), axes=("data", "model")):
-    """Small mesh for host-device tests (needs XLA host device count set)."""
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 RECOMMENDED_XLA_FLAGS = [

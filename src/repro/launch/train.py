@@ -61,7 +61,7 @@ def main() -> None:
     if n_dev >= 256:
         mesh = make_production_mesh(multi_pod=args.multi_pod)
     else:  # whatever this host offers (tests / single chip)
-        mesh = jax.make_mesh((n_dev, 1), ("data", "model"))
+        mesh = SH.make_mesh((n_dev, 1), ("data", "model"))
     rules = SH.make_rules(multi_pod=args.multi_pod,
                           fsdp=cfg.name in FSDP_ARCHS, sp=True)
     batch_axes = ("pod", "data") if args.multi_pod else ("data",)

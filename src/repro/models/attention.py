@@ -82,11 +82,7 @@ def _qkv(cfg: ModelConfig, p, x, positions):
         k = qkv[..., hq * dh : (hq + hkv) * dh].reshape(b, s, hkv, dh)
         v = qkv[..., (hq + hkv) * dh :].reshape(b, s, hkv, dh)
     else:
-        # head counts come from the param shapes, not cfg: under the mesh
-        # executor each device holds a column (head) slice of wq/wk/wv and
-        # projects straight to its LOCAL heads
-        hq = p["wq"]["w"].shape[-1] // dh
-        hkv = p["wk"]["w"].shape[-1] // dh
+        hq, hkv = cfg.num_q_heads, cfg.num_kv_heads
         q = L.linear(p["wq"], x).reshape(b, s, hq, dh)
         k = L.linear(p["wk"], x).reshape(b, s, hkv, dh)
         v = L.linear(p["wv"], x).reshape(b, s, hkv, dh)
@@ -99,16 +95,10 @@ def _qkv(cfg: ModelConfig, p, x, positions):
 
 
 def _local_heads(x, n_local: int, axis_name: str):
-    """This device's contiguous head block of a [B, S, H, D] projection.
-
-    No-op when the projection params were themselves head-sharded (the
-    tensor already holds only local heads); otherwise — fused-wqkv params
-    stay replicated — slice block `axis_index` out of the full head set.
-    RoPE is per-head/position-based, so slice-after-rope == rope-after-
-    slice and either entry point is bit-identical.
-    """
-    if x.shape[2] == n_local:
-        return x
+    """This device's contiguous head block of a [B, S, H, D] projection:
+    block `axis_index` of the full head set every device projects (the
+    serving params are replicated).  RoPE is per-head/position-based, so
+    slice-after-rope == rope-after-slice."""
     idx = jax.lax.axis_index(axis_name)
     return jax.lax.dynamic_slice_in_dim(x, idx * n_local, n_local, axis=2)
 

@@ -16,11 +16,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
-from repro.distributed.sharding import (
-    SHARD_MAP_NOCHECK as _SHARD_MAP_NOCHECK,
-    constrain,
-    shard_map as _shard_map,
-)
+from repro.distributed.sharding import constrain
 from repro.models import layers as L
 
 
@@ -170,12 +166,12 @@ def moe_ffn_dropless_ep(cfg: ModelConfig, p, x):
             ys.astype(jnp.float32) * w_flat[:, None])
         return jax.lax.psum(y, "model").reshape(xl.shape)
 
-    y = _shard_map(
+    y = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(batch_axes or None), P(), P("model"), P("model"),
                   P("model")),
         out_specs=P(batch_axes or None),
-        **_SHARD_MAP_NOCHECK,
+        check_vma=False,
     )(x, p["router"]["w"], p["w_gate"], p["w_up"], p["w_down"])
     if "shared" in p:
         y = y + L.mlp(p["shared"], x.reshape(-1, d)).astype(y.dtype

@@ -95,8 +95,6 @@ class CellCost:
 
 def extract_costs(compiled) -> tuple[float, float, dict]:
     ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):  # jax<0.5 returned [dict], newer: dict
-        ca = ca[0] if ca else {}
     flops = float(ca.get("flops", 0.0))
     bytes_ = float(ca.get("bytes accessed", 0.0))
     colls = collective_bytes(compiled.as_text())

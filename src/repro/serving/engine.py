@@ -104,6 +104,7 @@ import contextlib
 import dataclasses
 import functools
 import logging
+import time
 from typing import Sequence
 
 import jax
@@ -317,6 +318,11 @@ class Engine:
         self.cached_prefill_tokens = 0  # tokens skipped via the prefix cache
         self.launched_token_slots = 0  # token rows launched (incl. padding)
         self.compile_events: list[tuple] = []  # (kind, b, s, kcfg)/capture
+        # unified captures are compiled ahead of their first launch: the
+        # compiled programs by executable-cache key (their text shows
+        # which kernels run) and the seconds spent tracing + compiling
+        self.executables: dict[tuple, jax.stages.Compiled] = {}
+        self.compile_seconds = 0.0
         # device dispatches by kind ("unified" / "prefill" /
         # "prefill_cached" / "decode" / "sample"): the fused-sampling
         # acceptance tests assert a steady packed step adds exactly
@@ -325,6 +331,9 @@ class Engine:
         self._emitted: list[tuple[int, int]] = []  # (req_id, token)/step
         self.last_step_stats: dict | None = None
         self.last_step_logits = None  # device [S, V], debug_logits only
+        # (cache, batch) the last unified launch read, debug_logits only:
+        # what a replay of that step on another backend needs
+        self.last_launch = None
         self.last_generate: dict = {}  # drive-loop stats (see generate())
         self._compiled: dict[tuple, object] = {}
         # executable-cache key -> {"flops", "bytes_accessed"} | None:
@@ -398,18 +407,18 @@ class Engine:
 
     def _exe_cost(self, key: tuple, fn, *args) -> dict | None:
         """Best-effort XLA cost_analysis (flops / bytes accessed) of the
-        executable behind `key`, memoized per executable-cache key.  The
-        AOT lower+compile runs once per CAPTURE (after the launch was
-        recorded, so it never pollutes launch timing) and lets warm
+        executable behind `key`, memoized per executable-cache key.  A
+        unified capture reuses its ahead-of-launch compile; the padded
+        kinds lower+compile once per CAPTURE (after the launch was
+        recorded, so it never pollutes launch timing).  It lets warm
         launches stamp device-side cost into the latency grid — the refit
         can then split observed latency into a device-time floor and host
         overhead (`tune.refit_from_telemetry(separate_host_overhead=...)`)."""
         if key not in self._launch_costs:
             cost = None
             try:
-                ca = fn.lower(*args).compile().cost_analysis()
-                if isinstance(ca, (list, tuple)):  # jax < 0.5 returns a list
-                    ca = ca[0] if ca else {}
+                exe = self.executables.get(key) or fn.lower(*args).compile()
+                ca = exe.cost_analysis()
                 if ca:
                     cost = {
                         "flops": float(ca.get("flops", 0.0) or 0.0),
@@ -1038,6 +1047,12 @@ class Engine:
                           pack.kcfg)
         self.device_calls["unified"] += 1
         cache_in = self.cache
+        if exe_key not in self.executables:
+            # the launch below reuses this compile
+            t_compile = time.perf_counter()
+            self.executables[exe_key] = fn.lower(
+                self.params, cache_in, batch).compile()
+            self.compile_seconds += time.perf_counter() - t_compile
         t_launch = tel.clock.now() if tel else 0.0
         with self._launch_ctx("unified", pack.tokens):
             ret = fn(self.params, cache_in, batch)
@@ -1052,6 +1067,8 @@ class Engine:
             out, self.last_step_logits, new_cache = ret
         else:
             out, new_cache = ret
+        if self._debug_logits:
+            self.last_launch = (cache_in, batch)
         if tel:
             compiled = len(self.compile_events) > pre_captures
             timed = compiled or tel.time_this_launch()

@@ -9,16 +9,20 @@ builds the per-kernel-config unified executables the engine caches:
                           (bit-identical by construction: same callable,
                           same trace)
   TensorParallelExecutor  tp>1 — the step runs under shard_map over a
-                          ("tp",) mesh.  ONLY the attention head axis is
-                          sharded: wq/wk/wv column-parallel in whole
-                          heads, KV pages split on the head axis (every
-                          device holds num_kv_heads/tp heads of EVERY
-                          page, so page tables / slot_mapping /
-                          query_start_loc metadata stay replicated and
-                          the scheduler is untouched), one tiled
-                          all-gather of attention outputs before the
-                          replicated wo/head/sampling epilogue.  No
-                          contraction is ever split, so outputs are
+                          ("tp",) mesh.  ONLY the KV pages are sharded,
+                          on the head axis (every device holds
+                          num_kv_heads/tp heads of EVERY page, so page
+                          tables / slot_mapping / query_start_loc
+                          metadata stay replicated and the scheduler is
+                          untouched).  Params are replicated: each
+                          device projects all q/k/v heads, exactly as
+                          tp=1 does, keeps its own head block for
+                          attention, and one tiled all-gather of
+                          attention outputs precedes the replicated
+                          wo/head/sampling epilogue.  Every matmul has
+                          its tp=1 shape (on a TPU a head-sliced
+                          projection compiles to a different program
+                          and rounds differently), so outputs are
                           bit-identical to tp=1, and a shard_map-wrapped
                           jit is still ONE device dispatch per step.
   PipelineParallelExecutor pp>1 — interface stub: micro-batched packed
@@ -111,12 +115,11 @@ class TensorParallelExecutor(DeviceMeshExecutor):
                 f"tp={tp} needs {tp} devices but only "
                 f"{jax.device_count()} are visible; on CPU set "
                 f"XLA_FLAGS=--xla_force_host_platform_device_count={tp}")
-        self.mesh = jax.make_mesh((tp,), (self.AXIS,))
+        self.mesh = dsh.make_mesh((tp,), (self.AXIS,))
         self.shard = dsh.ShardCtx(axis=self.AXIS, size=tp)
 
     def place_params(self, params):
-        return jax.device_put(params, PS.assign_serve_param_shardings(
-            params, mesh=self.mesh, axis=self.AXIS))
+        return jax.device_put(params, NamedSharding(self.mesh, P()))
 
     def place_cache(self, cache):
         return jax.device_put(cache, PS.assign_cache_shardings(
@@ -148,16 +151,15 @@ class TensorParallelExecutor(DeviceMeshExecutor):
         def run(params, cache, batch):
             # spec trees come from the actual pytrees at trace time, so
             # one wrapper serves every param/cache layout
-            pspecs = PS.serve_param_specs(params, tp=self.tp,
-                                          axis=self.AXIS)
+            pspecs = jax.tree.map(lambda _: P(), params)
             cspecs = self._cache_specs(cache)
             bspecs = jax.tree.map(lambda _: P(), batch)
             # tokens/logits are replicated outputs; the cache comes back
             # sharded exactly as it went in
             out_specs = (P(),) * n_out + (cspecs,)
-            return dsh.shard_map(
+            return jax.shard_map(
                 body, mesh=self.mesh, in_specs=(pspecs, cspecs, bspecs),
-                out_specs=out_specs, **dsh.SHARD_MAP_NOCHECK,
+                out_specs=out_specs, check_vma=False,
             )(params, cache, batch)
 
         return jax.jit(run)

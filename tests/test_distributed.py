@@ -26,7 +26,8 @@ def test_sharding_rules_roundtrip():
     run_with_devices("""
 import jax, jax.numpy as jnp, numpy as np
 from repro.distributed import sharding as sh
-mesh = jax.make_mesh((2, 2), ("data", "model"))
+from repro.distributed.sharding import make_mesh
+mesh = make_mesh((2, 2), ("data", "model"))
 rules = sh.make_rules()
 with sh.use_rules(mesh, rules):
     assert sh.spec("batch", "seq", "heads", None) == \
@@ -45,11 +46,12 @@ def test_int8_error_feedback_allreduce():
     run_with_devices("""
 import functools
 import jax, jax.numpy as jnp, numpy as np
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 from repro.distributed.collectives import int8_ef_allgather, bf16_psum
+from repro.distributed.sharding import make_mesh
 
-mesh = jax.make_mesh((4,), ("data",))
+mesh = make_mesh((4,), ("data",))
 x = jnp.arange(32, dtype=jnp.float32).reshape(4, 8) / 7.3
 ef0 = jnp.zeros((8,), jnp.float32)
 
@@ -93,9 +95,10 @@ def test_ep_moe_matches_dropless():
 import jax, jax.numpy as jnp, numpy as np
 from repro.configs import ARCHS, reduced
 from repro.distributed import sharding as SH
+from repro.distributed.sharding import make_mesh
 from repro.models.moe import init_moe, moe_ffn_dropless, moe_ffn_dropless_ep
 
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_mesh((2, 4), ("data", "model"))
 cfg = reduced(ARCHS["deepseek-v2-236b"]).replace(dtype="float32")
 p = init_moe(cfg, jax.random.key(0))
 x = jax.random.normal(jax.random.key(1), (2, 16, cfg.d_model))
@@ -112,8 +115,9 @@ def test_pipeline_matches_sequential():
     run_with_devices("""
 import jax, jax.numpy as jnp, numpy as np
 from repro.distributed.pipeline import pipeline_apply, stage_split
+from repro.distributed.sharding import make_mesh
 
-mesh = jax.make_mesh((4,), ("pipe",))
+mesh = make_mesh((4,), ("pipe",))
 L, M, mb, d = 8, 6, 2, 16
 key = jax.random.key(0)
 w = jax.random.normal(key, (L, d, d)) * 0.3

@@ -17,12 +17,13 @@ from repro.configs import ARCHS, reduced
 from repro.configs.base import InputShape, input_specs
 from repro.distributed import param_sharding as PS
 from repro.distributed import sharding as SH
+from repro.distributed.sharding import make_mesh
 from repro.models import model as M
 from repro.roofline.analysis import extract_costs
 from repro.training.trainer import make_train_state_abstract, make_train_step
 import functools
 
-mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
 batch_axes = ("pod", "data")
 arch = os.environ["TEST_ARCH"]
 cfg = reduced(ARCHS[arch])

@@ -113,8 +113,9 @@ import jax.numpy as jnp
 import numpy as np
 from repro.core.paged import kv_cache as KV
 from repro.distributed import param_sharding as PS
+from repro.distributed.sharding import make_mesh
 
-mesh = jax.make_mesh((4,), ("tp",))
+mesh = make_mesh((4,), ("tp",))
 specs = KV.make_kv_cache_specs(2, 8, 1, 6, 4, 16, 16, "float32")
 local = KV.shard_cache_specs(specs, 4)
 assert local["k_pages"].shape == (2, 2, 1, 6, 4, 16), local["k_pages"].shape
@@ -145,25 +146,24 @@ print("OK")
 
 
 def test_serve_param_specs_shard_only_qkv_heads():
+    """Under tp no param is sharded, q/k/v projections included: every
+    device projects all heads with the tp=1 matmul shapes (a head-sliced
+    projection compiles to a different program on a TPU and breaks
+    token identity with tp=1) and keeps its own head block."""
     run_with_devices("""
 import jax
 from jax.sharding import PartitionSpec as P
 import serving_harness as H
-from repro.distributed import param_sharding as PS
+from repro.serving import executor as X
 
 cfg, params = H.build_cfg_params(num_q_heads=8, num_kv_heads=4)
-specs = PS.serve_param_specs(params, tp=4)
-flat = jax.tree_util.tree_flatten_with_path(specs)[0]
-sharded = {jax.tree_util.keystr(p) for p, s in flat if s != P()}
-assert sharded, "qkv projections must be sharded"
-for path in sharded:
-    assert any(f"'{n}'" in path for n in ("wq", "wk", "wv")), path
-# and only the LAST (output/head) dim is the sharded one — block params
-# are layer-stacked [L, d, H*dh]
-for path, s in flat:
-    if jax.tree_util.keystr(path) in sharded:
-        assert tuple(s)[-1] == "tp" and \\
-            all(a is None for a in tuple(s)[:-1]), (path, s)
+ex = X.TensorParallelExecutor(cfg, tp=4, backend="xla", max_seqs=2,
+                              fused=True, seed=0, debug_logits=False)
+placed = ex.place_params(params)
+for path, leaf in jax.tree_util.tree_flatten_with_path(placed)[0]:
+    assert leaf.sharding.spec == P(), (jax.tree_util.keystr(path),
+                                       leaf.sharding.spec)
+    assert len(leaf.sharding.device_set) == 4, jax.tree_util.keystr(path)
 print("OK")
 """, n=4)
 

@@ -22,8 +22,9 @@ def test_collective_parse_from_real_compile():
     code = """
 import jax, jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
+from repro.distributed.sharding import make_mesh
 from repro.roofline.analysis import collective_bytes
-mesh = jax.make_mesh((4,), ("model",))
+mesh = make_mesh((4,), ("model",))
 def f(x, w):
     y = x @ w  # w sharded on contracting dim -> psum
     return jax.lax.with_sharding_constraint(
