@@ -6,7 +6,10 @@ engine/scheduler/prefix-cache call.  When an `Engine` is built without
 telemetry (`telemetry=None`, the default) none of these hooks run and the
 serving loop does not call `block_until_ready` for timing — the
 observability layer costs nothing when disabled (the
-`telemetry-overhead` bench scenario guards the enabled cost too).
+`telemetry-overhead` bench scenario guards the enabled cost too).  The
+engine's phase spans (`serving/spans.py`) are the exception by design:
+their profiler annotations are always on, and with telemetry attached
+each span also lands here through `record_phase`.
 
 Beyond metrics and traces, `Telemetry` accumulates the **latency grid**:
 per (phase, bucketed `BatchProfile`, `KernelConfig`) observed launch
@@ -83,8 +86,9 @@ class Telemetry:
             buckets=LATENCY_BUCKETS_S)
         self._phase_h = m.histogram(
             "repro_step_phase_seconds",
-            "Wall-clock of one step phase (schedule/pack/launch/sample/"
-            "host, plus `overlap`: host work for step N+1 done while "
+            "Wall-clock of one step phase (schedule/pack/compile/"
+            "dispatch/prefix_index/sample/consume/host/draft, the timed "
+            "launch, plus `overlap`: host work for step N+1 done while "
             "step N's launch was still in flight — the async "
             "double-buffered loop).",
             labelnames=("phase",), buckets=LATENCY_BUCKETS_S)
@@ -166,7 +170,7 @@ class Telemetry:
     # -- step phases ---------------------------------------------------
 
     def record_phase(self, name: str, t0: float, t1: float, **args) -> None:
-        """One `block_until_ready`-bounded step region [t0, t1]."""
+        """One step phase [t0, t1] on the telemetry clock."""
         self._phase_h.observe(t1 - t0, phase=name)
         self.tracer.complete(name, t0, t1, track="engine", **args)
 

@@ -4,17 +4,19 @@ Three layers:
 
 * `Tracer` — a bounded in-memory buffer of Chrome/Perfetto trace events
   (the `chrome://tracing` / https://ui.perfetto.dev JSON array format).
-  The engine wraps each `block_until_ready`-bounded region of a step
-  (schedule, pack, launch, sample, host bookkeeping) in a span, so a
-  step renders as a stacked timeline per track.
+  The engine's phase spans (`serving/spans.py`: schedule, pack,
+  dispatch, sample, host bookkeeping, ...) land here with telemetry on,
+  so a step renders as a stacked timeline per track.
 * `RequestTracker` — per-request lifecycle records (arrival → admission →
   chunk completions → first token → finish) that yield the serving
   metrics that matter to a caller: TTFT (time to first token), ITL
-  (inter-token latency), queue time, and preemption counts.  Each event
-  feeds the metrics registry (histograms/counters) and, when a tracer is
-  attached, emits one "X" event per finished request on its own
-  `req-<id>` track so request lifetimes can be eyeballed against step
-  spans in the same Perfetto view.
+  (inter-token latency), queue time, and preemption counts.  Submission,
+  admission, queue wait and preemptions come from the stamps the
+  scheduler sets on the `Request` itself, so the two never disagree.
+  Each event feeds the metrics registry (histograms/counters) and, when a
+  tracer is attached, emits one "X" event per finished request on its
+  own `req-<id>` track so request lifetimes can be eyeballed against
+  step spans in the same Perfetto view.
 * `FlightRecorder` — the always-on crash-dump analog for latency: with
   the tracer in ring mode (`ring=True`, newest events overwrite oldest)
   the buffer always holds the *most recent* window of the run, and the
@@ -35,7 +37,7 @@ import logging
 import math
 import os
 from contextlib import contextmanager
-from dataclasses import dataclass, field
+from dataclasses import dataclass
 
 from .clock import Clock, PerfCounterClock
 from .metrics import LATENCY_BUCKETS_S, Registry
@@ -143,7 +145,10 @@ class Tracer:
 
 @dataclass
 class RequestRecord:
-    """Lifecycle milestones of one request (seconds on the trace clock)."""
+    """Lifecycle milestones of one request (seconds on the trace clock).
+    `submit_t`, `admit_t`, `queue_time` and `preemptions` mirror the
+    request's own stamps (`submitted_at`, `admitted_at`, `queue_wait`,
+    `preemptions`)."""
 
     req_id: int
     submit_t: float
@@ -156,11 +161,6 @@ class RequestRecord:
     num_chunks: int = 0
     preemptions: int = 0
     queue_time: float = 0.0
-    # True while the request sits in the waiting queue (initially, and
-    # again after every preemption); the next chunk/token event closes
-    # the wait that started at `_wait_since`.
-    queued: bool = True
-    _wait_since: float = field(default=0.0, repr=False)
 
     @property
     def ttft(self) -> float | None:
@@ -209,33 +209,31 @@ class RequestTracker:
     def _now(self, t: float | None) -> float:
         return self.clock.now() if t is None else t
 
-    def _dequeue(self, rec: RequestRecord, t: float) -> None:
-        wait = max(t - rec._wait_since, 0.0)
-        rec.queue_time += wait
-        rec.queued = False
-        if rec.admit_t is None:
-            rec.admit_t = t
-        self._queue.observe(wait)
-
-    def submit(self, req, t: float | None = None) -> RequestRecord:
-        t = self._now(t)
+    def submit(self, req) -> RequestRecord:
+        """`req` entered the queue at its `submitted_at` stamp."""
         rec = RequestRecord(
-            req_id=req.req_id, submit_t=t,
-            prompt_tokens=len(getattr(req, "prompt", ()) or ()),
-            _wait_since=t)
+            req_id=req.req_id, submit_t=req.submitted_at,
+            prompt_tokens=len(getattr(req, "prompt", ()) or ()))
         self.records[req.req_id] = rec
         self._events.inc(event="submitted")
         return rec
 
-    def chunk(self, req, t: float | None = None) -> None:
+    def admit(self, req, wait: float) -> None:
+        """`req` was admitted after waiting `wait` seconds; its stamps
+        already count the wait."""
+        rec = self.records.get(req.req_id)
+        if rec is None:
+            return
+        rec.admit_t = req.admitted_at
+        rec.queue_time = req.queue_wait
+        self._queue.observe(wait)
+
+    def chunk(self, req) -> None:
         """A prefill chunk for `req` completed this step."""
         rec = self.records.get(req.req_id)
         if rec is None:
             return
-        t = self._now(t)
         rec.num_chunks += 1
-        if rec.queued:
-            self._dequeue(rec, t)
         self._events.inc(event="chunk")
 
     def token(self, req, t: float | None = None) -> None:
@@ -244,8 +242,6 @@ class RequestTracker:
         if rec is None:
             return
         t = self._now(t)
-        if rec.queued:  # decode-only admission path (no prefill chunk seen)
-            self._dequeue(rec, t)
         rec.num_tokens += 1
         if rec.first_token_t is None:
             rec.first_token_t = t
@@ -258,17 +254,16 @@ class RequestTracker:
         rec.last_token_t = t
         self._events.inc(event="token")
 
-    def preempt(self, req, t: float | None = None) -> None:
+    def preempt(self, req) -> None:
+        """`req` went back to the queue at its `queued_at` stamp."""
         rec = self.records.get(req.req_id)
         if rec is None:
             return
-        t = self._now(t)
-        rec.preemptions += 1
-        rec.queued = True
-        rec._wait_since = t
+        rec.preemptions = req.preemptions
         self._events.inc(event="preempted")
         if self.tracer:
-            self.tracer.instant("preempted", t, track=f"req-{rec.req_id}")
+            self.tracer.instant("preempted", req.queued_at,
+                                track=f"req-{rec.req_id}")
 
     def finish(self, req, t: float | None = None) -> None:
         rec = self.records.get(req.req_id)

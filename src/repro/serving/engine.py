@@ -53,6 +53,12 @@ that finished or was preempted in flight is discarded by its
 `_spec_epoch`).  Telemetry records the host work that overlapped device
 execution as `overlap` phase spans.
 
+Phase spans (`serving/spans.py`): each host phase of a step runs under a
+`repro.<phase>` profiler annotation carrying the step's id — schedule,
+pack, compile, dispatch, prefix_index, wait_tokens, consume, finish —
+always on and without a host sync, so a profile puts every idle stretch
+of the device beside the host phase that held it.
+
 Static-shape discipline = the TPU analog of CUDA-graph capture (paper §6.2):
 every jitted executable is keyed by its bucket tuple; the packed path
 buckets on the pow2 total-token count alone, the padded path on
@@ -119,6 +125,7 @@ from repro.serving.executor import make_executor
 from repro.serving.prefix_cache import PrefixCache
 from repro.serving.request import PENDING_TOKEN, Request, State
 from repro.serving.scheduler import Scheduler
+from repro.serving.spans import Span
 from repro.utils.misc import cdiv, next_power_of_2
 
 log = logging.getLogger(__name__)
@@ -154,7 +161,8 @@ class _Inflight:
     slot of the async loop)."""
     dec: object  # scheduler decision
     stats: dict
-    t0: float
+    t0: float  # telemetry clock at the step's start (0.0 without)
+    step: int  # the step's id: the step_idx it has when it finishes
     pack: _PackedLaunch | None = None
     out: object = None  # device [S] sampled ids (fused) or [S, V] logits
 
@@ -390,20 +398,11 @@ class Engine:
         return self._compiled[key]
 
     # ------------------------------------------------------------------
-    # device-side timing (telemetry only)
+    # phase spans and device-side cost
     # ------------------------------------------------------------------
 
-    def _launch_ctx(self, kind: str, tokens: int):
-        """jax.profiler annotation around a launch so a device profile
-        (`jax.profiler.start_trace`) attributes device time to the
-        executable kind; a no-op without telemetry."""
-        if self.telemetry is None:
-            return contextlib.nullcontext()
-        try:
-            return jax.profiler.TraceAnnotation(
-                f"repro.launch.{kind}", tokens=tokens, tp=self.tp)
-        except Exception:  # noqa: BLE001 — annotation is best-effort
-            return contextlib.nullcontext()
+    def _span(self, name: str, step: int, **kw) -> Span:
+        return Span(name, step, self.telemetry, **kw)
 
     def _exe_cost(self, key: tuple, fn, *args) -> dict | None:
         """Best-effort XLA cost_analysis (flops / bytes accessed) of the
@@ -678,148 +677,124 @@ class Engine:
     # one engine step
     # ------------------------------------------------------------------
 
-    def _schedule_and_pack(self, t_step: float, prev_rows=None,
+    def _schedule_and_pack(self, step: int, prev_rows=None,
                            prev_out=None) -> _Inflight:
         """The front half of a step, shared by the synchronous `step()`
         and the async `_begin_step()`: schedule, account, update page
         tables, pack, and DISPATCH — no blocking on device results."""
-        tel = self.telemetry
         self._last_dispatch = {}
-        dec = self.sched.step(self.step_idx)
-        if tel:
-            tel.record_phase("schedule", t_step, tel.clock.now(),
-                             decode=len(dec.decode_reqs),
-                             prefill=len(dec.prefill_reqs))
-        new_tokens = dec.scheduled_prefill_tokens
-        # cached tokens are reported on a request's FIRST chunk (the one
-        # starting exactly at the matched prefix); later chunk-resumes
-        # start past it and charge nothing
-        cached_tokens = sum(r.num_cached_tokens for r in dec.prefill_reqs
-                            if r.chunk_start == r.num_cached_tokens)
-        self.prefilled_tokens += new_tokens
-        self.cached_prefill_tokens += cached_tokens
-        stats = {"prefill": len(dec.prefill_reqs),
-                 "decode": len(dec.decode_reqs),
-                 "preempted": len(dec.preempted),
-                 "prefill_tokens": new_tokens,
-                 "cached_tokens": cached_tokens,
-                 "partial_prefills": sum(1 for r in dec.prefill_reqs
-                                         if not r.prefill_done),
-                 "budget_utilization": dec.budget_utilization}
-        if self.prefix_cache is not None:
-            stats.update(self.prefix_cache.stats())
-        for req in dec.prefill_reqs:
-            row = np.zeros((self.pages_per_seq,), np.int32)
-            row[: len(req.pages)] = req.pages
-            self.page_table[req.slot] = row
-        for req in dec.decode_reqs:  # page growth
-            row = self.page_table[req.slot]
-            row[: len(req.pages)] = req.pages
+        with self._span("schedule", step) as sp:
+            dec = self.sched.step(self.step_idx)
+            sp.args.update(decode=len(dec.decode_reqs),
+                           prefill=len(dec.prefill_reqs))
+            new_tokens = dec.scheduled_prefill_tokens
+            # cached tokens are reported on a request's FIRST chunk (the
+            # one starting exactly at the matched prefix); later
+            # chunk-resumes start past it and charge nothing
+            cached_tokens = sum(r.num_cached_tokens for r in dec.prefill_reqs
+                                if r.chunk_start == r.num_cached_tokens)
+            self.prefilled_tokens += new_tokens
+            self.cached_prefill_tokens += cached_tokens
+            stats = {"prefill": len(dec.prefill_reqs),
+                     "decode": len(dec.decode_reqs),
+                     "preempted": len(dec.preempted),
+                     "prefill_tokens": new_tokens,
+                     "cached_tokens": cached_tokens,
+                     "partial_prefills": sum(1 for r in dec.prefill_reqs
+                                             if not r.prefill_done),
+                     "budget_utilization": dec.budget_utilization}
+            if self.prefix_cache is not None:
+                stats.update(self.prefix_cache.stats())
+            for req in dec.prefill_reqs:
+                row = np.zeros((self.pages_per_seq,), np.int32)
+                row[: len(req.pages)] = req.pages
+                self.page_table[req.slot] = row
+            for req in dec.decode_reqs:  # page growth
+                row = self.page_table[req.slot]
+                row[: len(req.pages)] = req.pages
 
-        flight = _Inflight(dec=dec, stats=stats, t0=t_step)
+        flight = _Inflight(dec=dec, stats=stats, t0=sp.t0, step=step)
         if self._packed and (dec.decode_reqs or dec.prefill_reqs):
-            batch, pack = self._pack_unified(
-                dec.decode_reqs, dec.prefill_reqs,
-                prev_rows=prev_rows, prev_out=prev_out)
-            flight.out = self._launch_unified(batch, pack)
+            with self._span("pack", step) as sp:
+                batch, pack = self._pack_unified(
+                    dec.decode_reqs, dec.prefill_reqs,
+                    prev_rows=prev_rows, prev_out=prev_out)
+                sp.args["tokens"] = pack.tokens
+            flight.out = self._launch_unified(batch, pack, step)
             flight.pack = pack
         if dec.prefill_reqs and self.prefix_cache is not None:
-            for r in dec.prefill_reqs:
-                # index the now-written full pages (up to this chunk's
-                # end) so concurrent shared-prefix requests can reuse
-                # them immediately — even mid-chunked-prefill; the
-                # cursor keeps the chained hashing O(prompt) overall
-                # (context_len is set at pack time, so this is safe to do
-                # while the launch is still in flight)
-                r.cache_cursor = self.prefix_cache.insert_incremental(
-                    r.prompt, r.pages, r.context_len, r.cache_cursor)
+            with self._span("prefix_index", step):
+                for r in dec.prefill_reqs:
+                    # index the now-written full pages (up to this chunk's
+                    # end) so concurrent shared-prefix requests can reuse
+                    # them immediately — even mid-chunked-prefill; the
+                    # cursor keeps the chained hashing O(prompt) overall
+                    # (context_len is set at pack time, so this is safe to
+                    # do while the launch is still in flight)
+                    r.cache_cursor = self.prefix_cache.insert_incremental(
+                        r.prompt, r.pages, r.context_len, r.cache_cursor)
         stats["dispatch"] = dict(self._last_dispatch)
         return flight
 
     def _finish_step(self, flight: _Inflight) -> dict:
-        """The back half of a step: block on the launch's sampled tokens,
-        fold them into request state, process finishes, close out stats
-        and telemetry."""
-        tel = self.telemetry
+        """The back half of a packed step: block on the launch's sampled
+        tokens, fold them into request state, then close the step."""
         self._emitted = []
         stats = flight.stats
         self._step_spec = (0, 0, 0)
         if flight.pack is not None:
-            t_sample = tel.clock.now() if tel else 0.0
-            toks = self._host_tokens(flight.out, flight.pack)
-            if tel:
-                tel.record_phase("sample", t_sample, tel.clock.now())
-            self._consume_unified(flight.pack, toks)
+            with self._span("wait_tokens", flight.step, phase="sample"):
+                toks = self._host_tokens(flight.out, flight.pack)
+            with self._span("consume", flight.step):
+                self._consume_unified(flight.pack, toks)
         if self._spec:
             p, a, e = self._step_spec
             stats["spec_proposed"] = p
             stats["spec_accepted"] = a
             stats["spec_emitted"] = e
-        t_host = tel.clock.now() if tel else 0.0
-        for req in list(self.sched.running):
-            # a request whose LAST token is still in flight (unfilled
-            # placeholder) must not finish yet — it finishes next step,
-            # once the token lands
-            if req.prefill_done and req.done and not req._placeholder:
-                slot = req.slot  # finish() releases the slot
-                self.sched.finish(req)
-                if slot is not None:
-                    self.page_table[slot] = 0
-        # pool occupancy AFTER finishes released their pages, so the
-        # snapshot matches the harness's pages-conserved invariant
-        stats["pool"] = self.alloc.mesh_stats(self.tp)
-        stats["sampled_tokens"] = len(self._emitted)
-        if tel:
-            t_end = tel.clock.now()
-            tel.record_phase("host", t_host, t_end)
-            tel.record_step(t0=flight.t0, t1=t_end, decision=flight.dec,
-                            stats=stats, engine=self)
-        if self.refit is not None:  # hot-swap boundary (obs.refit)
-            self.refit.on_step(self)
-            stats["refit_swaps"] = self.refit.swaps
-        self.step_idx += 1
-        self.last_step_stats = stats
-        return stats
+        return self._close_step(flight)
 
     def step(self) -> dict:
-        tel = self.telemetry
-        t_step = tel.clock.now() if tel else 0.0
+        flight = self._schedule_and_pack(self.step_idx)
         if self._packed:
-            flight = self._schedule_and_pack(t_step)
             return self._finish_step(flight)
-        # padded per-kind path: run, then reuse the same back half (its
-        # launches already consumed their results inline)
-        flight = self._schedule_and_pack(t_step)
+        # padded per-kind path: run, then close the step (its launches
+        # already consumed their results inline)
         self._emitted = []
         dec = flight.dec
         if dec.prefill_reqs:
             self._run_prefill(dec.prefill_reqs)
         if dec.decode_reqs:
             self._run_decode(dec.decode_reqs)
-        return self._finish_padded(flight)
+        return self._close_step(flight)
 
-    def _finish_padded(self, flight: _Inflight) -> dict:
-        """Padded-path step epilogue: finishes + stats (tokens were
-        already emitted inside the per-kind runners)."""
+    def _close_step(self, flight: _Inflight) -> dict:
+        """The end of every step, packed or padded: process finishes,
+        snapshot the pool, close out stats and telemetry, and apply any
+        pending heuristics hot-swap."""
         tel = self.telemetry
         stats = flight.stats
-        t_host = tel.clock.now() if tel else 0.0
-        for req in list(self.sched.running):
-            if req.prefill_done and req.done and not req._placeholder:
-                slot = req.slot
-                self.sched.finish(req)
-                if slot is not None:
-                    self.page_table[slot] = 0
-        stats["pool"] = self.alloc.mesh_stats(self.tp)
-        stats["sampled_tokens"] = len(self._emitted)
-        if tel:
-            t_end = tel.clock.now()
-            tel.record_phase("host", t_host, t_end)
-            tel.record_step(t0=flight.t0, t1=t_end, decision=flight.dec,
-                            stats=stats, engine=self)
-        if self.refit is not None:  # hot-swap boundary (obs.refit)
-            self.refit.on_step(self)
-            stats["refit_swaps"] = self.refit.swaps
+        with self._span("finish", flight.step, phase="host"):
+            for req in list(self.sched.running):
+                # a request whose LAST token is still in flight (unfilled
+                # placeholder) must not finish yet — it finishes next
+                # step, once the token lands
+                if req.prefill_done and req.done and not req._placeholder:
+                    slot = req.slot  # finish() releases the slot
+                    self.sched.finish(req)
+                    if slot is not None:
+                        self.page_table[slot] = 0
+            # pool occupancy AFTER finishes released their pages, so the
+            # snapshot matches the harness's pages-conserved invariant
+            stats["pool"] = self.alloc.mesh_stats(self.tp)
+            stats["sampled_tokens"] = len(self._emitted)
+            if tel:
+                tel.record_step(t0=flight.t0, t1=tel.clock.now(),
+                                decision=flight.dec, stats=stats,
+                                engine=self)
+            if self.refit is not None:  # hot-swap boundary (obs.refit)
+                self.refit.on_step(self)
+                stats["refit_swaps"] = self.refit.swaps
         self.step_idx += 1
         self.last_step_stats = stats
         return stats
@@ -832,25 +807,25 @@ class Engine:
         every length / paging / max_new_tokens computation sees post-step
         state."""
         tel = self.telemetry
-        t0 = tel.clock.now() if tel else 0.0
-        prev_rows: dict[int, int] = {}
-        if prev is not None and prev.pack is not None:
-            for r, row, epoch in prev.pack.rows:
-                if r._spec_epoch != epoch or \
-                        r.state not in (State.RUNNING, State.PREFILLING):
-                    continue
-                prev_rows[r.req_id] = row
-                if not r._placeholder:
-                    r.output.append(PENDING_TOKEN)
-                    r._placeholder = True
-        flight = self._schedule_and_pack(
-            t0, prev_rows=prev_rows,
-            prev_out=prev.out if prev is not None else None)
-        if tel and prev is not None and prev.pack is not None:
-            # the host work above (schedule/pack/dispatch) ran while the
-            # previous launch was still in flight
-            tel.record_phase("overlap", t0, tel.clock.now())
-        return flight
+        # the host work below (schedule/pack/dispatch) runs while the
+        # previous launch is still in flight
+        overlap = tel.phase("overlap") if tel and prev is not None \
+            and prev.pack is not None else contextlib.nullcontext()
+        with overlap:
+            prev_rows: dict[int, int] = {}
+            if prev is not None and prev.pack is not None:
+                for r, row, epoch in prev.pack.rows:
+                    if r._spec_epoch != epoch or \
+                            r.state not in (State.RUNNING, State.PREFILLING):
+                        continue
+                    prev_rows[r.req_id] = row
+                    if not r._placeholder:
+                        r.output.append(PENDING_TOKEN)
+                        r._placeholder = True
+            # step_idx counts finished steps; `prev` has not finished yet
+            return self._schedule_and_pack(
+                self.step_idx + (prev is not None), prev_rows=prev_rows,
+                prev_out=prev.out if prev is not None else None)
 
     def _positions(self, pos: np.ndarray) -> jnp.ndarray:
         p = jnp.asarray(pos, jnp.int32)
@@ -909,8 +884,6 @@ class Engine:
         the GUARANTEED minimum (total_len: the last real token's KV is
         written unconditionally); `_consume_unified` finalizes it to
         cover exactly the accepted tokens and rolls the rest back."""
-        tel = self.telemetry
-        t_pack = tel.clock.now() if tel else 0.0
         ms = self.max_seqs
         ps = self.cfg.page_size
         bucket = lambda r: next_power_of_2(max(r.total_len, 1))
@@ -1032,14 +1005,12 @@ class Engine:
                 batch["spec_lens"] = jnp.asarray(spec_lens)
         else:
             pack.sampling = self._sampling_rows(s, fill)
-        if tel:
-            tel.record_phase("pack", t_pack, tel.clock.now(), tokens=t)
         return batch, pack
 
-    def _launch_unified(self, batch: dict, pack: _PackedLaunch):
-        """Dispatch one packed launch; returns the device-side result
-        ([S] sampled ids fused, [S, V] last logits otherwise) WITHOUT
-        transferring it to the host."""
+    def _launch_unified(self, batch: dict, pack: _PackedLaunch, step: int):
+        """Dispatch one packed launch of step `step`; returns the
+        device-side result ([S] sampled ids fused, [S, V] last logits
+        otherwise) WITHOUT transferring it to the host."""
         tel = self.telemetry
         pre_captures = len(self.compile_events)
         exe_key = ("unified", 2 * self.max_seqs, pack.tokens, pack.kcfg)
@@ -1049,12 +1020,12 @@ class Engine:
         cache_in = self.cache
         if exe_key not in self.executables:
             # the launch below reuses this compile
-            t_compile = time.perf_counter()
-            self.executables[exe_key] = fn.lower(
-                self.params, cache_in, batch).compile()
-            self.compile_seconds += time.perf_counter() - t_compile
-        t_launch = tel.clock.now() if tel else 0.0
-        with self._launch_ctx("unified", pack.tokens):
+            with self._span("compile", step):
+                t_compile = time.perf_counter()
+                self.executables[exe_key] = fn.lower(
+                    self.params, cache_in, batch).compile()
+                self.compile_seconds += time.perf_counter() - t_compile
+        with self._span("dispatch", step) as launch:
             ret = fn(self.params, cache_in, batch)
         if self._spec:
             # verify contract: tokens [S, K+1] + num_emitted [S]
@@ -1075,7 +1046,7 @@ class Engine:
             if timed:
                 jax.block_until_ready(out)
             tel.record_launch(
-                "unified", pack.profile, pack.kcfg, t_launch,
+                "unified", pack.profile, pack.kcfg, launch.t0,
                 tel.clock.now(), compiled=compiled, tokens=pack.tokens,
                 grid_phase="unified", timed=timed,
                 cost=self._launch_costs.get(exe_key))
@@ -1173,61 +1144,37 @@ class Engine:
                 if r.chunk_start + r.num_scheduled_tokens
                 == r.num_prompt_tokens]
         if done:
-            t_sample = tel.clock.now() if tel else 0.0
-            temps, topp, topk, streams, ngen = self._sampling_rows(
-                logits.shape[0], done)
-            self.device_calls["sample"] += 1
-            toks = np.asarray(self._sample_fn(
-                logits, jnp.asarray(temps), jnp.asarray(topp),
-                jnp.asarray(topk), jnp.asarray(streams),
-                jnp.asarray(ngen)))
+            with self._span("wait_tokens", self.step_idx, phase="sample"):
+                temps, topp, topk, streams, ngen = self._sampling_rows(
+                    logits.shape[0], done)
+                self.device_calls["sample"] += 1
+                toks = np.asarray(self._sample_fn(
+                    logits, jnp.asarray(temps), jnp.asarray(topp),
+                    jnp.asarray(topk), jnp.asarray(streams),
+                    jnp.asarray(ngen)))
+        with self._span("consume", self.step_idx):
             for i, r in done:
                 self._emit_token(r, int(toks[i]))
-            if tel:
-                tel.record_phase("sample", t_sample, tel.clock.now())
-        for r in reqs:
-            r.context_len = r.chunk_start + r.num_scheduled_tokens
-        if tel:
-            done_set = {r.req_id for _, r in done}
             for r in reqs:
-                tel.requests.chunk(r)
-                if r.req_id in done_set:
-                    tel.requests.token(r)
+                r.context_len = r.chunk_start + r.num_scheduled_tokens
+            if tel:
+                done_set = {r.req_id for _, r in done}
+                for r in reqs:
+                    tel.requests.chunk(r)
+                    if r.req_id in done_set:
+                        tel.requests.token(r)
 
-    def _run_prefill_fresh(self, reqs: list[Request]) -> None:
+    def _launch_padded(self, key: tuple, profile, cache_in, batch: dict,
+                       tokens: int, grid_phase: str | None = None):
+        """Dispatch one padded-path launch of the executable `key` =
+        (kind, batch bucket, seq bucket, KernelConfig) and, with
+        telemetry, time it; returns (logits, new_cache)."""
         tel = self.telemetry
-        t_pack = tel.clock.now() if tel else 0.0
-        b = next_power_of_2(len(reqs))
-        max_len = max(r.num_scheduled_tokens for r in reqs)
-        s = max(next_power_of_2(max_len), self.cfg.page_size)
-        tokens = np.zeros((b, s), np.int32)
-        qlens = np.zeros((b,), np.int32)
-        pt = np.zeros((b, self.pages_per_seq), np.int32)
-        pos = np.tile(np.arange(s, dtype=np.int32)[None], (b, 1))
-        for i, r in enumerate(reqs):
-            n = r.num_scheduled_tokens
-            tokens[i, :n] = r.prompt[:n]
-            qlens[i] = n
-            pt[i] = self.page_table[r.slot]
-
-        cache_in = self._prefill_cache_view(b)
-        profile = self._prefill_profile(reqs)
-        kcfg = self._dispatch("prefill", profile)
+        kind = key[0].split("/")[0]
         pre_captures = len(self.compile_events)
-        exe_key = ("prefill", b, s, kcfg)
-        fn = self._get_fn("prefill", b, s, kcfg)
-        self.device_calls["prefill"] += 1
-        batch = {
-            "inputs": jnp.asarray(tokens),
-            "positions": self._positions(pos),
-            "page_table": jnp.asarray(pt),
-            "context_lens": jnp.asarray(qlens),
-            "query_lens": jnp.asarray(qlens),
-        }
-        if tel:
-            t_launch = tel.clock.now()
-            tel.record_phase("pack", t_pack, t_launch, tokens=b * s)
-        with self._launch_ctx("prefill", b * s):
+        fn = self._get_fn(*key)
+        self.device_calls[kind] += 1
+        with self._span("dispatch", self.step_idx) as launch:
             logits, new_cache = fn(self.params, cache_in, batch)
         if tel:
             compiled = len(self.compile_events) > pre_captures
@@ -1235,12 +1182,43 @@ class Engine:
             if timed:
                 jax.block_until_ready(logits)
             tel.record_launch(
-                "prefill", profile, kcfg, t_launch, tel.clock.now(),
-                compiled=compiled, tokens=b * s, grid_phase="prefill",
-                timed=timed, cost=self._launch_costs.get(exe_key))
+                kind, profile, key[3], launch.t0, tel.clock.now(),
+                compiled=compiled, tokens=tokens, grid_phase=grid_phase,
+                timed=timed, cost=self._launch_costs.get(key))
             if compiled:
-                self._exe_cost(exe_key, fn, self.params, cache_in, batch)
-        self.launched_token_slots += b * s
+                self._exe_cost(key, fn, self.params, cache_in, batch)
+        self.launched_token_slots += tokens
+        return logits, new_cache
+
+    def _run_prefill_fresh(self, reqs: list[Request]) -> None:
+        with self._span("pack", self.step_idx) as sp:
+            b = next_power_of_2(len(reqs))
+            max_len = max(r.num_scheduled_tokens for r in reqs)
+            s = max(next_power_of_2(max_len), self.cfg.page_size)
+            sp.args["tokens"] = b * s
+            tokens = np.zeros((b, s), np.int32)
+            qlens = np.zeros((b,), np.int32)
+            pt = np.zeros((b, self.pages_per_seq), np.int32)
+            pos = np.tile(np.arange(s, dtype=np.int32)[None], (b, 1))
+            for i, r in enumerate(reqs):
+                n = r.num_scheduled_tokens
+                tokens[i, :n] = r.prompt[:n]
+                qlens[i] = n
+                pt[i] = self.page_table[r.slot]
+
+            cache_in = self._prefill_cache_view(b)
+            profile = self._prefill_profile(reqs)
+            kcfg = self._dispatch("prefill", profile)
+            batch = {
+                "inputs": jnp.asarray(tokens),
+                "positions": self._positions(pos),
+                "page_table": jnp.asarray(pt),
+                "context_lens": jnp.asarray(qlens),
+                "query_lens": jnp.asarray(qlens),
+            }
+        logits, new_cache = self._launch_padded(
+            ("prefill", b, s, kcfg), profile, cache_in, batch, b * s,
+            grid_phase="prefill")
         self._merge_prefill_cache(new_cache, [r.slot for r in reqs])
         self._finish_chunk(reqs, logits)
 
@@ -1249,121 +1227,85 @@ class Engine:
         chunk; attention reads the prior context — earlier chunks and/or a
         shared cached prefix — back from the pages
         (context_lens = chunk_start + chunk)."""
-        tel = self.telemetry
-        t_pack = tel.clock.now() if tel else 0.0
-        b = next_power_of_2(len(reqs))
-        max_chunk = max(r.num_scheduled_tokens for r in reqs)
-        s = max(next_power_of_2(max_chunk), self.cfg.page_size)
-        # page-table width bucket: attend only over the pages the longest
-        # context actually uses, not the full max_model_len table (the xla
-        # path gathers the whole table width)
-        max_ctx = max(r.chunk_start + r.num_scheduled_tokens for r in reqs)
-        np_b = min(self.pages_per_seq,
-                   next_power_of_2(cdiv(max_ctx, self.cfg.page_size)))
-        tokens = np.zeros((b, s), np.int32)
-        qlens = np.zeros((b,), np.int32)
-        ctx = np.zeros((b,), np.int32)
-        pt = np.zeros((b, np_b), np.int32)
-        pos = np.tile(np.arange(s, dtype=np.int32)[None], (b, 1))
-        for i, r in enumerate(reqs):
-            chunk = r.prompt[r.chunk_start:
-                             r.chunk_start + r.num_scheduled_tokens]
-            tokens[i, : len(chunk)] = chunk
-            qlens[i] = len(chunk)
-            ctx[i] = r.chunk_start + r.num_scheduled_tokens
-            pos[i] += r.chunk_start  # absolute positions
-            pt[i] = self.page_table[r.slot][:np_b]
+        with self._span("pack", self.step_idx) as sp:
+            b = next_power_of_2(len(reqs))
+            max_chunk = max(r.num_scheduled_tokens for r in reqs)
+            s = max(next_power_of_2(max_chunk), self.cfg.page_size)
+            sp.args["tokens"] = b * s
+            # page-table width bucket: attend only over the pages the
+            # longest context actually uses, not the full max_model_len
+            # table (the xla path gathers the whole table width)
+            max_ctx = max(r.chunk_start + r.num_scheduled_tokens
+                          for r in reqs)
+            np_b = min(self.pages_per_seq,
+                       next_power_of_2(cdiv(max_ctx, self.cfg.page_size)))
+            tokens = np.zeros((b, s), np.int32)
+            qlens = np.zeros((b,), np.int32)
+            ctx = np.zeros((b,), np.int32)
+            pt = np.zeros((b, np_b), np.int32)
+            pos = np.tile(np.arange(s, dtype=np.int32)[None], (b, 1))
+            for i, r in enumerate(reqs):
+                chunk = r.prompt[r.chunk_start:
+                                 r.chunk_start + r.num_scheduled_tokens]
+                tokens[i, : len(chunk)] = chunk
+                qlens[i] = len(chunk)
+                ctx[i] = r.chunk_start + r.num_scheduled_tokens
+                pos[i] += r.chunk_start  # absolute positions
+                pt[i] = self.page_table[r.slot][:np_b]
 
-        cache_in = self._prefill_cache_view(b)
-        profile = self._prefill_profile(reqs)
-        kcfg = self._dispatch("prefill_cached", profile)
-        pre_captures = len(self.compile_events)
-        exe_key = (f"prefill_cached/np{np_b}", b, s, kcfg)
-        fn = self._get_fn(f"prefill_cached/np{np_b}", b, s, kcfg)
-        self.device_calls["prefill_cached"] += 1
-        batch = {
-            "inputs": jnp.asarray(tokens),
-            "positions": self._positions(pos),
-            "page_table": jnp.asarray(pt),
-            "context_lens": jnp.asarray(ctx),
-            "query_lens": jnp.asarray(qlens),
-        }
-        if tel:
-            t_launch = tel.clock.now()
-            tel.record_phase("pack", t_pack, t_launch, tokens=b * s)
-        with self._launch_ctx("prefill_cached", b * s):
-            logits, new_cache = fn(self.params, cache_in, batch)
-        if tel:
-            compiled = len(self.compile_events) > pre_captures
-            timed = compiled or tel.time_this_launch()
-            if timed:
-                jax.block_until_ready(logits)
-            tel.record_launch(
-                "prefill_cached", profile, kcfg, t_launch, tel.clock.now(),
-                compiled=compiled, tokens=b * s, grid_phase="prefill",
-                timed=timed, cost=self._launch_costs.get(exe_key))
-            if compiled:
-                self._exe_cost(exe_key, fn, self.params, cache_in, batch)
-        self.launched_token_slots += b * s
+            cache_in = self._prefill_cache_view(b)
+            profile = self._prefill_profile(reqs)
+            kcfg = self._dispatch("prefill_cached", profile)
+            batch = {
+                "inputs": jnp.asarray(tokens),
+                "positions": self._positions(pos),
+                "page_table": jnp.asarray(pt),
+                "context_lens": jnp.asarray(ctx),
+                "query_lens": jnp.asarray(qlens),
+            }
+        logits, new_cache = self._launch_padded(
+            (f"prefill_cached/np{np_b}", b, s, kcfg), profile, cache_in,
+            batch, b * s, grid_phase="prefill")
         self._merge_prefill_cache(new_cache, [r.slot for r in reqs])
         self._finish_chunk(reqs, logits)
 
     def _run_decode(self, reqs: list[Request]) -> None:
         tel = self.telemetry
-        t_pack = tel.clock.now() if tel else 0.0
         b = self.max_seqs  # static decode batch (paper C5)
-        tokens = np.zeros((b, 1), np.int32)
-        pos = np.full((b, 1), -1, np.int32)
-        ctx = np.zeros((b,), np.int32)
-        for r in reqs:
-            tokens[r.slot, 0] = r.output[-1] if r.output else r.prompt[-1]
-            pos[r.slot, 0] = r.total_len - 1
-            ctx[r.slot] = r.total_len
-        temps, topp, topk, streams, ngen = self._sampling_rows(
-            b, [(r.slot, r) for r in reqs])
-        profile = self._decode_profile(reqs)
-        kcfg = self._dispatch("decode", profile)
-        pre_captures = len(self.compile_events)
-        exe_key = ("decode", b, 1, kcfg)
-        fn = self._get_fn("decode", b, 1, kcfg)
-        self.device_calls["decode"] += 1
-        cache_in = self.cache
-        batch = {
-            "inputs": jnp.asarray(tokens),
-            "positions": self._positions(pos),
-            "page_table": jnp.asarray(self.page_table),
-            "context_lens": jnp.asarray(ctx),
-        }
-        if tel:
-            t_launch = tel.clock.now()
-            tel.record_phase("pack", t_pack, t_launch, tokens=b)
-        with self._launch_ctx("decode", b):
-            logits, new_cache = fn(self.params, cache_in, batch)
-        if tel:
-            compiled = len(self.compile_events) > pre_captures
-            timed = compiled or tel.time_this_launch()
-            if timed:
-                jax.block_until_ready(logits)
-            tel.record_launch(
-                "decode", profile, kcfg, t_launch, tel.clock.now(),
-                compiled=compiled, tokens=b, timed=timed,
-                cost=self._launch_costs.get(exe_key))
-            if compiled:
-                self._exe_cost(exe_key, fn, self.params, cache_in, batch)
-        self.cache = new_cache
-        self.launched_token_slots += b
-        t_sample = tel.clock.now() if tel else 0.0
-        self.device_calls["sample"] += 1
-        toks = np.asarray(self._sample_fn(
-            logits, jnp.asarray(temps), jnp.asarray(topp),
-            jnp.asarray(topk), jnp.asarray(streams), jnp.asarray(ngen)))
-        if tel:
-            tel.record_phase("sample", t_sample, tel.clock.now())
-        for r in reqs:
-            r.context_len = r.total_len
-            self._emit_token(r, int(toks[r.slot]))
-            if tel:
-                tel.requests.token(r)
+        with self._span("pack", self.step_idx, tokens=b):
+            tokens = np.zeros((b, 1), np.int32)
+            pos = np.full((b, 1), -1, np.int32)
+            ctx = np.zeros((b,), np.int32)
+            for r in reqs:
+                tokens[r.slot, 0] = r.output[-1] if r.output \
+                    else r.prompt[-1]
+                pos[r.slot, 0] = r.total_len - 1
+                ctx[r.slot] = r.total_len
+            temps, topp, topk, streams, ngen = self._sampling_rows(
+                b, [(r.slot, r) for r in reqs])
+            profile = self._decode_profile(reqs)
+            kcfg = self._dispatch("decode", profile)
+            cache_in = self.cache
+            batch = {
+                "inputs": jnp.asarray(tokens),
+                "positions": self._positions(pos),
+                "page_table": jnp.asarray(self.page_table),
+                "context_lens": jnp.asarray(ctx),
+            }
+        logits, self.cache = self._launch_padded(
+            ("decode", b, 1, kcfg), profile, cache_in, batch, b)
+        with self._span("wait_tokens", self.step_idx, phase="sample"):
+            self.device_calls["sample"] += 1
+            toks = np.asarray(self._sample_fn(
+                logits, jnp.asarray(temps), jnp.asarray(topp),
+                jnp.asarray(topk), jnp.asarray(streams),
+                jnp.asarray(ngen)))
+        with self._span("consume", self.step_idx):
+            for r in reqs:
+                r.context_len = r.total_len
+                self._emit_token(r, int(toks[r.slot]))
+                if tel:
+                    tel.requests.token(r)
 
     # ------------------------------------------------------------------
     # slot-indexed (SSM) cache plumbing

@@ -70,6 +70,14 @@ class Request:
     # the drafts in-graph.  Drafts are proposals only — they never enter
     # `output` until the verify launch accepts them.
     spec_tokens: list[int] = dataclasses.field(default_factory=list)
+    # lifecycle stamps (scheduler-owned), in seconds on the scheduler's
+    # clock: the telemetry's when one is attached, else time.perf_counter
+    submitted_at: float | None = None  # Scheduler.add
+    admitted_at: float | None = None  # the first admission
+    queued_at: float | None = None  # entered the queue (submission or
+    #                                 preemption); None while admitted
+    queue_wait: float = 0.0  # seconds waited for admission, every admission
+    preemptions: int = 0
 
     def discard_speculative(self) -> None:
         """Invalidate in-flight sampled tokens (called on preemption):

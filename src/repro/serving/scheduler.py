@@ -45,10 +45,12 @@ the engine (paper §6.1 'computation of metadata').
 from __future__ import annotations
 
 import dataclasses
+import time
 
 from repro.core.paged.allocator import PageAllocator
 from repro.serving.prefix_cache import PrefixCache
 from repro.serving.request import Request, State
+from repro.serving.spans import Span
 
 
 @dataclasses.dataclass
@@ -93,6 +95,10 @@ class Scheduler:
         self.prefix_cache = prefix_cache
         self.enable_chunked_prefill = enable_chunked_prefill
         self.telemetry = telemetry  # obs.Telemetry | None
+        # the clock of the requests' lifecycle stamps
+        self.clock = telemetry.clock.now if telemetry is not None \
+            else time.perf_counter
+        self.preemptions = 0  # requests evicted back to the queue, in all
         self.drafter = drafter  # serving.draft.Drafter | None (spec decode)
         self.waiting: list[Request] = []
         self.running: list[Request] = []
@@ -115,6 +121,7 @@ class Scheduler:
             f"request needs "
             f"{self.alloc.pages_needed(req.num_prompt_tokens + req.max_new_tokens)}"
             f" pages, pool holds {self.alloc.num_pages - 1}")
+        req.submitted_at = req.queued_at = self.clock()
         self.waiting.append(req)
         if self.telemetry is not None:
             self.telemetry.requests.submit(req)
@@ -173,6 +180,9 @@ class Scheduler:
         req.chunk_start = 0
         req.num_scheduled_tokens = 0
         req.cache_cursor = None
+        req.preemptions += 1
+        self.preemptions += 1
+        req.queued_at = self.clock()
         self.running.remove(req)
         self.waiting.insert(0, req)
         if self.telemetry is not None:
@@ -360,8 +370,15 @@ class Scheduler:
             budget -= chunk
             self.running.append(req)
             prefill_reqs.append(req)
+            now = self.clock()
+            wait = now - req.queued_at
+            req.queue_wait += wait
+            req.queued_at = None
+            if req.admitted_at is None:
+                req.admitted_at = now
             if self.telemetry is not None:
                 self.telemetry.scheduler_event("admitted")
+                self.telemetry.requests.admit(req, wait)
 
         # --- 4. speculative drafts (spec-decode engines only) -------------
         # Runs AFTER admissions so the chunk-region row count is final: a
@@ -374,31 +391,28 @@ class Scheduler:
         # they must not displace prefill admissions).
         spec_scheduled = 0
         if self.drafter is not None and decode_reqs:
-            t0 = self.telemetry.clock.now() if self.telemetry else 0.0
-            spec_slots = self.max_seqs - len(prefill_reqs)
-            for req in decode_reqs:
-                if spec_slots <= 0:
-                    break
-                drafts = self.drafter.propose(req)
-                while drafts and self.alloc.pages_to_cover(
-                        len(req.pages),
-                        req.total_len + len(drafts)) > self.alloc.free_pages:
-                    drafts.pop()
-                if not drafts:
-                    continue
-                need = self.alloc.pages_to_cover(
-                    len(req.pages), req.total_len + len(drafts))
-                if need > 0:
-                    req.pages.extend(self.alloc.allocate(need))
-                req.spec_tokens = drafts
-                spec_scheduled += len(drafts)
-                spec_slots -= 1
-                if self.enable_chunked_prefill:
-                    budget -= len(drafts)
-            if self.telemetry is not None:
-                self.telemetry.record_phase(
-                    "draft", t0, self.telemetry.clock.now(),
-                    tokens=spec_scheduled)
+            with Span("draft", step_idx, self.telemetry) as sp:
+                spec_slots = self.max_seqs - len(prefill_reqs)
+                for req in decode_reqs:
+                    if spec_slots <= 0:
+                        break
+                    drafts = self.drafter.propose(req)
+                    while drafts and self.alloc.pages_to_cover(
+                            len(req.pages), req.total_len + len(drafts)) \
+                            > self.alloc.free_pages:
+                        drafts.pop()
+                    if not drafts:
+                        continue
+                    need = self.alloc.pages_to_cover(
+                        len(req.pages), req.total_len + len(drafts))
+                    if need > 0:
+                        req.pages.extend(self.alloc.allocate(need))
+                    req.spec_tokens = drafts
+                    spec_scheduled += len(drafts)
+                    spec_slots -= 1
+                    if self.enable_chunked_prefill:
+                        budget -= len(drafts)
+                sp.args["tokens"] = spec_scheduled
 
         # --- liveness backstop --------------------------------------------
         # Every resident request is a stalled chunked prefill (they jointly

@@ -167,8 +167,10 @@ def assert_telemetry_consistent(res: RunResult) -> None:
     assert m.value("repro_steps_total") == res.num_steps
     assert (m.value("repro_launched_token_slots_total")
             == eng.launched_token_slots)
+    # num_generated counts every token a request kept, those that a
+    # preemption folded into its prompt too (len(output) elsewhere)
     assert (m.value("repro_tokens_total", kind="sampled")
-            == sum(len(r.output) for r in res.requests))
+            == sum(r.num_generated for r in res.requests))
     assert (m.value("repro_tokens_total", kind="prefill")
             == res.total("prefill_tokens"))
     assert (m.value("repro_tokens_total", kind="cached_prefill")
@@ -192,11 +194,23 @@ def assert_telemetry_consistent(res: RunResult) -> None:
     assert len(recs) == len(res.requests)
     for r in res.requests:
         rec = recs[r.req_id]
-        assert rec.num_tokens == len(r.output), (rec, r.output)
-        assert rec.prompt_tokens == r.num_prompt_tokens
+        folded = r.num_generated - len(r.output)
+        assert rec.num_tokens == r.num_generated, (rec, r.output)
+        assert rec.prompt_tokens == r.num_prompt_tokens - folded
         if r.output:
             assert rec.first_token_t is not None
             assert rec.ttft is not None and rec.ttft >= 0.0
+        # queue wait and preemptions are the request's own stamps, in
+        # lifecycle order: submitted <= admitted <= first token
+        assert (rec.submit_t, rec.admit_t, rec.queue_time,
+                rec.preemptions) == (r.submitted_at, r.admitted_at,
+                                     r.queue_wait, r.preemptions)
+        if r.output:
+            assert (r.submitted_at <= r.admitted_at <= rec.first_token_t
+                    <= rec.finish_t)
+            assert r.queue_wait >= r.admitted_at - r.submitted_at
+    assert (sum(r.preemptions for r in res.requests)
+            == eng.sched.preemptions == res.total("preempted"))
 
     # pool gauges reflect the allocator at the last step
     pool = eng.alloc.stats()

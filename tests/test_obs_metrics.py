@@ -223,24 +223,46 @@ def test_tracer_capacity_bound():
 
 
 def _req(i, prompt_len=5):
-    return SimpleNamespace(req_id=i, prompt=list(range(prompt_len)))
+    """A request as `Scheduler.add` leaves it: submitted and queued at 0."""
+    return SimpleNamespace(req_id=i, prompt=list(range(prompt_len)),
+                           submitted_at=0.0, queued_at=0.0, admitted_at=None,
+                           queue_wait=0.0, preemptions=0)
+
+
+def _admit(trk, r, t):
+    """Stamp an admission at t as the scheduler does, then report it."""
+    wait = t - r.queued_at
+    r.queue_wait += wait
+    r.queued_at = None
+    if r.admitted_at is None:
+        r.admitted_at = t
+    trk.admit(r, wait)
+
+
+def _preempt(trk, r, t):
+    r.preemptions += 1
+    r.queued_at = t
+    trk.preempt(r)
 
 
 def test_request_lifecycle_ttft_itl_queue():
     reg = Registry()
     trk = RequestTracker(reg, Tracer(clock=FakeClock()))
     r = _req(0, prompt_len=7)
-    rec = trk.submit(r, t=0.0)
-    trk.chunk(r, t=2.0)       # admission: 2s queued
+    rec = trk.submit(r)
+    _admit(trk, r, t=2.0)     # admission: 2s queued
+    trk.chunk(r)
     trk.token(r, t=3.0)       # first token
     trk.token(r, t=4.0)       # itl 1.0
-    trk.preempt(r, t=5.0)     # back to the waiting queue
-    trk.token(r, t=9.0)       # re-admission: +4s queued; itl 5.0
+    _preempt(trk, r, t=5.0)   # back to the waiting queue
+    _admit(trk, r, t=9.0)     # re-admission: +4s queued
+    trk.token(r, t=9.0)       # itl 5.0
     trk.finish(r, t=10.0)
 
     assert rec.prompt_tokens == 7
     assert rec.ttft == pytest.approx(3.0)
     assert rec.e2e == pytest.approx(10.0)
+    assert rec.admit_t == pytest.approx(2.0)  # the first admission
     assert rec.queue_time == pytest.approx(6.0)
     assert rec.num_tokens == 3
     assert rec.preemptions == 1
@@ -252,6 +274,7 @@ def test_request_lifecycle_ttft_itl_queue():
     # histograms saw the same milestones (bucketed, so bound-level checks)
     assert reg.families()["repro_request_ttft_seconds"].get()["count"] == 1
     assert reg.families()["repro_request_itl_seconds"].get()["count"] == 2
+    assert reg.families()["repro_request_queue_seconds"].get()["count"] == 2
 
 
 def test_request_tracker_unknown_request_is_noop():

@@ -8,8 +8,12 @@ what the telemetry reports is re-derivable from engine ground truth
 the loop from the ISSUE: a mixed chunked trace -> latency grid ->
 `refit_from_telemetry` -> a heuristics file `heuristics.load` accepts.
 """
+import glob
 import json
+import os
+import re
 
+import jax
 import numpy as np
 import pytest
 
@@ -17,11 +21,93 @@ import serving_harness as H
 from repro.autotune.tune import refit_from_telemetry
 from repro.core.attention import heuristics
 from repro.obs import FakeClock, Telemetry
+from repro.serving.request import make_requests
 
 
 @pytest.fixture(scope="module")
 def smollm():
     return H.build_cfg_params()
+
+
+# one letter per engine span; a step's spans, in time order, spell a
+# word of its path's pattern (the padded path runs one pack/dispatch/
+# consume per executable kind, and only a prompt-completing chunk or a
+# decode samples)
+SPAN_LETTERS = {"repro.schedule": "S", "repro.pack": "P",
+                "repro.compile": "C", "repro.dispatch": "X",
+                "repro.prefix_index": "I", "repro.wait_tokens": "W",
+                "repro.consume": "K", "repro.finish": "F"}
+PACKED_STEP = r"S(PC?X)?I?(WK)?F"
+PATHS = {
+    "async": ({}, PACKED_STEP),
+    "sync": ({"fused_sampling": False}, PACKED_STEP),
+    "padded": ({"packed_attention": False}, r"SI?(PXW?K)*F"),
+}
+
+
+def _engine_spans(log_dir) -> list[tuple[str, int, int, int]]:
+    """(name, start ns, end ns, step) of every `repro.*` span in the
+    profile under `log_dir`, in start order."""
+    from jax.profiler import ProfileData
+    [path] = glob.glob(os.path.join(log_dir, "**", "*.xplane.pb"),
+                       recursive=True)
+    out = []
+    for plane in ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name.startswith("repro."):
+                    out.append((ev.name, int(ev.start_ns),
+                                int(ev.start_ns + ev.duration_ns),
+                                dict(ev.stats)["step"]))
+    return sorted(out, key=lambda s: (s[1], -s[2]))
+
+
+@pytest.mark.parametrize("path", sorted(PATHS))
+def test_stream_phase_spans_in_profiler_trace(smollm, tmp_path, monkeypatch,
+                                              path):
+    """Without telemetry `stream()` writes its phase spans into a
+    `jax.profiler` trace on every path (the double-buffered packed loop,
+    the synchronous packed step, the padded per-kind step): each finished
+    step has its spans, tagged with its id, in phase order and never
+    overlapping.  It calls no `block_until_ready`, and tracing changes no
+    token."""
+    cfg, params = smollm
+    kw, pattern = PATHS[path]
+    prompts = H.make_prompts(cfg, np.random.default_rng(11), (14, 6, 21, 9))
+
+    def run():
+        eng = H.build_engine(cfg, params, max_seqs=2,
+                             enable_chunked_prefill=True,
+                             enable_prefix_caching=True,
+                             max_prefill_tokens=16, **kw)
+        reqs = make_requests(prompts, max_new_tokens=5)
+        for r in reqs:
+            eng.submit(r)
+        list(eng.stream())
+        return eng, [r.output for r in reqs]
+
+    _, plain = run()
+    syncs = []
+    sync = jax.block_until_ready
+    monkeypatch.setattr(jax, "block_until_ready",
+                        lambda x: syncs.append(1) or sync(x))
+    with jax.profiler.trace(str(tmp_path)):
+        eng, traced = run()
+    assert traced == plain
+    assert syncs == []
+
+    spans = _engine_spans(str(tmp_path))
+    steps: dict[int, list] = {}
+    for sp in spans:
+        steps.setdefault(sp[3], []).append(sp)
+    assert sorted(steps) == list(range(eng.step_idx))
+    for step, ss in steps.items():
+        word = "".join(SPAN_LETTERS[n] for n, *_ in ss)
+        assert re.fullmatch(pattern, word), (step, word)
+    assert {"repro.dispatch", "repro.wait_tokens"} <= {n for n, *_ in spans}
+    # one thread drives every step: no two spans overlap
+    for a, b in zip(spans, spans[1:]):
+        assert a[2] <= b[1], (a, b)
 
 
 @pytest.fixture(scope="module")

@@ -18,6 +18,7 @@ except ImportError:  # collect-and-skip fallback (requirements-dev.txt)
 import serving_harness as H
 from repro.core.attention import heuristics
 from repro.core.paged.allocator import OutOfPages, PageAllocator
+from repro.obs import FakeClock, Telemetry
 from repro.serving.request import Request
 
 
@@ -54,6 +55,30 @@ def test_engine_preemption_under_page_pressure(smollm):
                        max_model_len=64),
         H.make_prompts(cfg, rng, (30, 30)), max_new_tokens=16)
     assert all(len(out) == 16 for out in run.outputs)
+
+
+def test_request_stamps_under_preemption(smollm):
+    """A pool too small for both requests' full lengths forces
+    preemption: every request's stamps are in lifecycle order, each
+    preemption is counted once on the request and on the scheduler, a
+    preempted request's queue wait includes its wait for re-admission,
+    and the telemetry's request records agree with the stamps."""
+    cfg, params = smollm
+    rng = np.random.default_rng(2)
+    res = H.run_requests(
+        H.build_engine(cfg, params, max_seqs=2, num_pages=7,
+                       max_model_len=128,
+                       telemetry=Telemetry(clock=FakeClock(tick=1e-3))),
+        H.make_prompts(cfg, rng, (30, 30)), max_new_tokens=20)
+    sched = res.engine.sched
+    assert sched.preemptions >= 1
+    for r in res.requests:
+        assert r.submitted_at < r.admitted_at and r.queued_at is None
+    preempted = [r for r in res.requests if r.preemptions]
+    assert sum(r.preemptions for r in preempted) == sched.preemptions
+    for r in preempted:
+        assert r.queue_wait > r.admitted_at - r.submitted_at
+    H.assert_telemetry_consistent(res)
 
 
 def test_engine_static_decode_batch_and_bucketing(smollm):
