@@ -156,6 +156,8 @@ def mixed_step_check(cfg, params) -> None:
         stats = eng.step()
         if stats["decode"] and stats["prefill"]:
             break
+    # the engine's copy of that launch's cache: the Pallas launch consumed
+    # the original, and the XLA replay below consumes this one
     cache_in, batch = eng.last_launch
     xla = SingleDeviceExecutor(
         cfg, backend="xla", max_seqs=eng.max_seqs, fused=True,
@@ -178,9 +180,10 @@ def mixed_step_check(cfg, params) -> None:
 
 def kernel_reference_check(cfg) -> None:
     """float32 `paged_attention_unified` against the plain reference at
-    the model's head counts: 8 decode rows (one dead) and two chunks, one
-    resumed after 300 tokens of context."""
-    hq, hkv, d = cfg.num_q_heads, cfg.num_kv_heads, cfg.resolved_head_dim
+    the model's head counts and at SmolLM's head_dim 64: 8 decode rows
+    (one dead) and two chunks, one resumed after 300 tokens of context,
+    reading the last layer of a two-layer pool; then the in-place pool
+    write against its `.at[].set` reference."""
     ps, np_ = cfg.page_size, 64
     rng = np.random.default_rng(SEED + 2)
     nd = 8
@@ -193,22 +196,45 @@ def kernel_reference_check(cfg) -> None:
     qsl = np.concatenate([[0], np.cumsum(qlens)]).astype(np.int32)
     p = s * np_ + 1
     f32 = jnp.float32
-    q = jnp.asarray(rng.standard_normal((t, hq, d)), f32)
-    kp = jnp.asarray(rng.standard_normal((hkv, p, ps, d)), f32)
-    vp = jnp.asarray(rng.standard_normal((hkv, p, ps, d)), f32)
     pt = jnp.asarray(rng.permutation(p - 1)[: s * np_].reshape(s, np_) + 1,
                      jnp.int32)
-    args = (q, kp, vp, pt, jnp.asarray(ctx), jnp.asarray(qsl),
-            jnp.asarray(qlens))
-    with jax.default_matmul_precision("highest"):
-        want = np.asarray(ref.paged_attention_prefill_ref(*args))
-    for variant in ("baseline", "gqa", "segmented"):
-        got = np.asarray(ops.paged_attention_unified(
-            *args, num_decode_seqs=nd, variant=variant, num_segments=8))
-        err = float((np.abs(got - want) / (1.0 + np.abs(want))).max())
-        print(f"float32 paged_attention_unified/{variant} vs reference: "
-              f"max |d|/(1+|ref|) {err:.2e} (tol {F32_KERNEL_TOL})")
-        check(err <= F32_KERNEL_TOL, f"{variant} kernel differs from ref")
+    heads = {(cfg.num_q_heads, cfg.num_kv_heads, cfg.resolved_head_dim),
+             (9, 3, 64)}
+    for hq, hkv, d in sorted(heads):
+        q = jnp.asarray(rng.standard_normal((t, hq, d)), f32)
+        kv = jnp.asarray(rng.standard_normal((2, hkv, p, ps, 2 * d)), f32)
+        args = (q, kv, 1, pt, jnp.asarray(ctx), jnp.asarray(qsl),
+                jnp.asarray(qlens))
+        with jax.default_matmul_precision("highest"):
+            want = np.asarray(ref.paged_attention_prefill_ref(*args))
+        for variant in ("baseline", "gqa", "segmented"):
+            got = np.asarray(ops.paged_attention_unified(
+                *args, num_decode_seqs=nd, variant=variant, num_segments=8))
+            err = float((np.abs(got - want) / (1.0 + np.abs(want))).max())
+            print(f"float32 paged_attention_unified/{variant} D={d} vs "
+                  f"reference: max |d|/(1+|ref|) {err:.2e} "
+                  f"(tol {F32_KERNEL_TOL})")
+            check(err <= F32_KERNEL_TOL,
+                  f"{variant} kernel differs from ref at D={d}")
+        # the step's rows: decode rows, a chunk that crosses a page, and
+        # trash slots, written into layer 1 in place
+        for dtype in (jnp.float32, jnp.bfloat16):
+            pool = kv.astype(dtype)
+            rows = jnp.asarray(rng.standard_normal((t, hkv, 2 * d)), dtype)
+            pos = np.concatenate([dec_ctx - 1, 300 + np.arange(t - nd)])
+            seq = np.concatenate([np.arange(nd), np.full(t - nd, nd + 1)])
+            slots = (np.asarray(pt)[seq, pos // ps] * ps + pos % ps)
+            slots[3] = p * ps  # the dead decode row: trash
+            slots[t - 5:] = p * ps  # padding: trash
+            slots = jnp.asarray(slots, jnp.int32)
+            want = np.asarray(ref.write_rows_ref(pool, 1, rows, slots),
+                              np.float32)
+            got = np.asarray(ops.write_rows(
+                pool, 1, rows, ops.write_plan(slots, ps, p)), np.float32)
+            bad = int((got != want).sum())
+            print(f"{jnp.dtype(dtype).name} write_rows D={d} vs reference: "
+                  f"{bad} elements differ")
+            check(bad == 0, f"in-place pool write differs at D={d}")
 
 
 def tp_phase(cfg) -> None:
@@ -238,7 +264,7 @@ def tp_phase(cfg) -> None:
                                 engine_kw=dict(engine_kw, tp=tp),
                                 requests=reqs, rate=len(reqs))
         if tp > 1:
-            pool = eng.cache["attn"]["k_pages"]
+            pool = eng.cache["attn"]["kv_pages"]
             devs = sorted(s.device.id for s in pool.addressable_shards)
             print(f"[tp={tp}] KV pool shards on devices {devs}, "
                   f"mesh {[dv.id for dv in eng.executor.mesh.devices.flat]}")

@@ -115,9 +115,8 @@ def _measure_on_device(scenario: Scenario, cfg: KernelConfig,
     """Wall-clock timing of the real kernels (paper §7.1 methodology:
     20 warmup + mean of 100).  Expects a single-phase scenario (see
     `measure`): all-decode batches time the decode kernel, batches with
-    query_lens > 1 time the Q-Block prefill kernel.  K and V use
-    independent page pools — aliasing V onto K would halve the DMA
-    traffic the sweep is supposed to measure."""
+    query_lens > 1 time the Q-Block prefill kernel.  The pool holds one
+    layer of the serving layout: a K|V row per token and KV head."""
     import jax
     import jax.numpy as jnp
     from repro.kernels.paged_attention import ops
@@ -125,11 +124,10 @@ def _measure_on_device(scenario: Scenario, cfg: KernelConfig,
     s = scenario
     np_ = -(-s.max_context // s.page_size)
     p = s.num_seqs * np_ + 1
-    kk, kv, kq = jax.random.split(jax.random.key(0), 3)
-    kp = jax.random.normal(kk, (s.num_kv_heads, p, s.page_size, s.head_dim),
-                           jnp.bfloat16)
-    vp = jax.random.normal(kv, (s.num_kv_heads, p, s.page_size, s.head_dim),
-                           jnp.bfloat16)
+    kk, kq = jax.random.split(jax.random.key(0))
+    pool = jax.random.normal(
+        kk, (1, s.num_kv_heads, p, s.page_size, 2 * s.head_dim),
+        jnp.bfloat16)
     pt = jnp.arange(1, 1 + s.num_seqs * np_,
                     dtype=jnp.int32).reshape(s.num_seqs, np_)
     ctx = jnp.asarray(s.context_lens, jnp.int32)
@@ -145,7 +143,7 @@ def _measure_on_device(scenario: Scenario, cfg: KernelConfig,
 
         def run():
             return ops.paged_attention_prefill(
-                q, kp, vp, pt, ctx, qsl, qlens, block_q=cfg.block_q,
+                q, pool, 0, pt, ctx, qsl, qlens, block_q=cfg.block_q,
                 tile=cfg.tile)
     else:
         q = jax.random.normal(kq, (s.num_seqs, s.num_q_heads, s.head_dim),
@@ -153,7 +151,7 @@ def _measure_on_device(scenario: Scenario, cfg: KernelConfig,
 
         def run():
             return ops.paged_attention_decode(
-                q, kp, vp, pt, ctx, variant=cfg.variant, tile=cfg.tile,
+                q, pool, 0, pt, ctx, variant=cfg.variant, tile=cfg.tile,
                 num_segments=cfg.num_segments)
 
     for _ in range(warmup):
@@ -184,11 +182,10 @@ def _measure_unified_on_device(scenario: Scenario, cfg: KernelConfig,
     nd = sum(1 for q in s.query_lens if q == 1)
     np_ = -(-s.max_context // s.page_size)
     p = s.num_seqs * np_ + 1
-    kk, kv, kq = jax.random.split(jax.random.key(0), 3)
-    kp = jax.random.normal(kk, (s.num_kv_heads, p, s.page_size, s.head_dim),
-                           jnp.bfloat16)
-    vp = jax.random.normal(kv, (s.num_kv_heads, p, s.page_size, s.head_dim),
-                           jnp.bfloat16)
+    kk, kq = jax.random.split(jax.random.key(0))
+    pool = jax.random.normal(
+        kk, (1, s.num_kv_heads, p, s.page_size, 2 * s.head_dim),
+        jnp.bfloat16)
     pt = jnp.arange(1, 1 + s.num_seqs * np_,
                     dtype=jnp.int32).reshape(s.num_seqs, np_)
     ctx = jnp.asarray(s.context_lens, jnp.int32)
@@ -201,7 +198,7 @@ def _measure_unified_on_device(scenario: Scenario, cfg: KernelConfig,
 
     def run():
         return ops.paged_attention_unified(
-            q, kp, vp, pt, ctx, qsl, qlens, num_decode_seqs=nd,
+            q, pool, 0, pt, ctx, qsl, qlens, num_decode_seqs=nd,
             variant=cfg.variant, tile=cfg.tile,
             num_segments=cfg.num_segments, block_q=cfg.block_q)
 

@@ -7,7 +7,9 @@ Two backends (paper Fig. 1/2 architecture):
             CPU-hosted tests of the full serving stack.
 
 Both consume the same paged cache + metadata and produce identical math
-(cross-checked in tests/test_attention_backends.py).
+(cross-checked in tests/test_unified_attention.py).  The cache is the whole
+stacked pool `kv_pages [L, Hkv, G, P, ps, Dk+Dv]` plus the layer to read
+(core/paged/kv_cache.py); `write_kv` puts a step's new rows into it.
 """
 from __future__ import annotations
 
@@ -15,7 +17,9 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.attention import heuristics
-from repro.core.paged.kv_cache import gather_pages, require_single_pool
+from repro.core.paged.kv_cache import (
+    gather_pages, require_single_pool, write_pages,
+)
 from repro.kernels.flash_attention.ref import flash_attention_xla
 from repro.kernels.paged_attention import ops as paged_ops
 
@@ -41,43 +45,72 @@ def _osm_finalize(acc, ll):
     return acc / ll[..., None]
 
 
+def _local_pool(kv_pages: jax.Array, site: str) -> jax.Array:
+    """The single-pool view the Pallas kernels read: [L, Hkv, P, ps, W]
+    (dropping the size-1 pool axis is free)."""
+    require_single_pool(kv_pages, site)
+    return kv_pages[:, :, 0]
+
+
+def _dense_kv(kv_pages, layer, page_table, dk: int, v_dim=None):
+    """Per-seq dense (k, v) of one layer, [S, Np*ps, Hkv, D] each.  GQA
+    rows split at `dk`; with `v_dim` (MLA) K is the whole latent row and V
+    its first `v_dim` lanes."""
+    kv = gather_pages(kv_pages, layer, page_table)
+    if v_dim is not None:
+        return kv, kv[..., :v_dim]
+    return kv[..., :dk], kv[..., dk:]
+
+
+def write_kv(backend: str, kv_pages: jax.Array, layer, rows: jax.Array,
+             slots: jax.Array, plan: dict | None) -> jax.Array:
+    """The pool with one layer's new rows [B, S, Hkv, W] written at their
+    pool-local slots [B, S] (out-of-range slots drop their row).
+      pallas  in place: `ops.write_rows` rewrites only the blocks that
+              receive a row; `plan` (`ops.write_plan` of the flattened
+              slots) is computed once per step and shared by every layer
+      xla     an XLA scatter, batched over pools (`plan` unused)"""
+    if backend != "pallas":
+        return write_pages(kv_pages, layer, rows, slots)
+    pool = _local_pool(kv_pages, "write_kv[pallas]")
+    out = paged_ops.write_rows(pool, layer, rows.reshape(-1, *rows.shape[2:]),
+                               plan)
+    return out[:, :, None]
+
+
 def decode_attention(
     backend: str,
     q: jax.Array,  # [S, Hq, Dk]
-    k_pages: jax.Array,  # [Hkv, num_pools, P, ps, Dk]
-    v_pages: jax.Array | None,  # same, or None (MLA latent view)
+    kv_pages: jax.Array,  # [L, Hkv, num_pools, P, ps, Dk+Dv]
+    layer,
     page_table: jax.Array,
     context_lens: jax.Array,
     *,
     scale: float | None = None,
-    v_dim: int | None = None,
+    v_dim: int | None = None,  # MLA: V is the latent prefix of K
     kernel_cfg: heuristics.KernelConfig | None = None,
     blockscan: bool = False,
 ) -> jax.Array:
     """Single-token decode. Returns [S, Hq, Dv]."""
     if backend == "xla":
-        q = _align_q_to_kv_shard(q, k_pages)
+        q = _align_q_to_kv_shard(q, kv_pages)
     if blockscan and backend == "xla":
         return decode_attention_blockscan(
-            q, k_pages, v_pages, page_table, context_lens, scale=scale,
+            q, kv_pages, layer, page_table, context_lens, scale=scale,
             v_dim=v_dim,
         )
     if backend == "pallas":
-        assert v_pages is not None, "pallas MLA decode uses the xla path"
-        require_single_pool(k_pages, "decode_attention[pallas]")
+        assert v_dim is None, "pallas MLA decode uses the xla path"
+        pool = _local_pool(kv_pages, "decode_attention[pallas]")
         cfg = heuristics.validate(
-            kernel_cfg or heuristics.KernelConfig("gqa"), k_pages.shape[3])
+            kernel_cfg or heuristics.KernelConfig("gqa"), pool.shape[3])
         return paged_ops.paged_attention_decode(
-            q, k_pages[:, 0], v_pages[:, 0], page_table, context_lens,
+            q, pool, layer, page_table, context_lens,
             variant=cfg.variant, tile=cfg.tile,
             num_segments=cfg.num_segments, scale=scale,
         )
     # --- xla backend: dense gather + masked online-softmax scan ---
-    k = gather_pages(k_pages, page_table)  # [S, L, Hkv, Dk]
-    if v_pages is None:
-        v = k[..., :v_dim]  # MLA: values are the latent prefix of K
-    else:
-        v = gather_pages(v_pages, page_table)
+    k, v = _dense_kv(kv_pages, layer, page_table, q.shape[-1], v_dim)
     out = flash_attention_xla(
         q[:, None], k, v, causal=False, scale=scale,
         kv_block=_pick_kv_block(k.shape[1]), kv_len=context_lens,
@@ -85,7 +118,7 @@ def decode_attention(
     return out[:, 0]
 
 
-def _align_q_to_kv_shard(q: jax.Array, k_pages: jax.Array) -> jax.Array:
+def _align_q_to_kv_shard(q: jax.Array, kv_pages: jax.Array) -> jax.Array:
     """§Perf: when the paged KV is head_dim-sharded (few KV heads), force Q
     into the SAME head_dim sharding. Otherwise GSPMD hits an 'involuntary
     full rematerialization' converting every gathered KV block from the
@@ -98,7 +131,7 @@ def _align_q_to_kv_shard(q: jax.Array, k_pages: jax.Array) -> jax.Array:
     if mesh is None:
         return q
     model_n = mesh.shape["model"]
-    hkv, dk = k_pages.shape[0], k_pages.shape[-1]
+    hkv, dk = kv_pages.shape[1], kv_pages.shape[-1]
     if hkv % model_n == 0 or dk % model_n:
         return q  # KV is head-sharded (or unshardable): leave Q alone
     spec = [None] * q.ndim
@@ -109,8 +142,8 @@ def _align_q_to_kv_shard(q: jax.Array, k_pages: jax.Array) -> jax.Array:
 
 def decode_attention_blockscan(
     q: jax.Array,  # [S, Hq, Dk]
-    k_pages: jax.Array,  # [Hkv, pools, P, ps, Dk]
-    v_pages: jax.Array | None,
+    kv_pages: jax.Array,  # [L, Hkv, pools, P, ps, Dk+Dv]
+    layer,
     page_table: jax.Array,  # [S, Np] pool-local
     context_lens: jax.Array,
     *,
@@ -123,7 +156,7 @@ def decode_attention_blockscan(
     HBM traffic; this variant streams page groups exactly like the Pallas
     kernel's DMA pipeline, so each KV byte is touched once."""
     s_, hq, dk = q.shape
-    hkv, pools, p_, ps, _ = k_pages.shape
+    _, hkv, pools, p_, ps, w = kv_pages.shape
     group = hq // hkv
     if scale is None:
         scale = dk**-0.5
@@ -134,7 +167,7 @@ def decode_attention_blockscan(
     pt = jnp.pad(page_table.astype(jnp.int32), ((0, 0), (0, pad)))
     pt_b = jnp.moveaxis(pt.reshape(s_, nblk, ppb), 1, 0)  # [nblk, S, ppb]
     qf = q.astype(jnp.float32).reshape(s_, hkv, group, dk)
-    dv = v_dim if v_pages is None else v_pages.shape[-1]
+    dv = v_dim if v_dim is not None else w - dk
 
     acc0 = jnp.zeros((s_, hkv, group, dv), jnp.float32)
     m0 = jnp.full((s_, hkv, group), _NEG, jnp.float32)
@@ -143,11 +176,7 @@ def decode_attention_blockscan(
     def step(carry, xs):
         acc, mm, ll = carry
         ptb, blk = xs  # [S, ppb]
-        k_blk = gather_pages(k_pages, ptb)  # [S, ppb*ps, Hkv, Dk]
-        if v_pages is None:
-            v_blk = k_blk[..., :v_dim]
-        else:
-            v_blk = gather_pages(v_pages, ptb)
+        k_blk, v_blk = _dense_kv(kv_pages, layer, ptb, dk, v_dim)
         sc = jnp.einsum("shgd,skhd->shgk", qf,
                         k_blk.astype(jnp.float32)) * scale
         kv_pos = blk * (ppb * ps) + jnp.arange(ppb * ps)
@@ -185,13 +214,12 @@ def prefill_attention_uniform(
     k_new: jax.Array,  # [B, S, Hkv, Dk] (the chunk's keys, already rope'd)
     v_new: jax.Array,  # [B, S, Hkv, Dv]
     query_lens: jax.Array,  # [B] (<= S; ragged-through-padding)
-    k_pages: jax.Array,
-    v_pages: jax.Array | None,
+    kv_pages: jax.Array,
+    layer,
     page_table: jax.Array,
     context_lens: jax.Array,
     *,
     scale: float | None = None,
-    v_dim: int | None = None,
     kernel_cfg: heuristics.KernelConfig | None = None,
 ) -> jax.Array:
     """Uniform-layout prefill over sequences with NO prior context
@@ -203,13 +231,13 @@ def prefill_attention_uniform(
     `prefill_attention_ragged` (token-packed)."""
     b, s, hq, dk = q.shape
     if backend == "pallas":
+        pool = _local_pool(kv_pages, "prefill_attention_uniform[pallas]")
         cfg = heuristics.validate(
-            kernel_cfg or heuristics.KernelConfig("gqa"), k_pages.shape[3])
-        require_single_pool(k_pages, "prefill_attention_uniform[pallas]")
+            kernel_cfg or heuristics.KernelConfig("gqa"), pool.shape[3])
         # uniform padded layout == ragged layout with stride-s starts
         qsl = (jnp.arange(b + 1, dtype=jnp.int32) * s)
         out = paged_ops.paged_attention_prefill(
-            q.reshape(b * s, hq, dk), k_pages[:, 0], v_pages[:, 0],
+            q.reshape(b * s, hq, dk), pool, layer,
             page_table, context_lens, qsl, query_lens.astype(jnp.int32),
             block_q=cfg.block_q, tile=cfg.tile, scale=scale,
         )
@@ -227,8 +255,8 @@ def prefill_attention_cached(
     backend: str,
     q: jax.Array,  # [B, S, Hq, Dk] (the uncached suffix chunk, padded)
     query_lens: jax.Array,  # [B] suffix lengths (<= S)
-    k_pages: jax.Array,
-    v_pages: jax.Array,
+    kv_pages: jax.Array,
+    layer,
     page_table: jax.Array,
     context_lens: jax.Array,  # [B] cached + suffix tokens
     *,
@@ -248,18 +276,17 @@ def prefill_attention_cached(
               q_offset, and cached lengths vary across the batch)."""
     b, s, hq, dk = q.shape
     if backend == "pallas":
+        pool = _local_pool(kv_pages, "prefill_attention_cached[pallas]")
         cfg = heuristics.validate(
-            kernel_cfg or heuristics.KernelConfig("gqa"), k_pages.shape[3])
-        require_single_pool(k_pages, "prefill_attention_cached[pallas]")
+            kernel_cfg or heuristics.KernelConfig("gqa"), pool.shape[3])
         qsl = jnp.arange(b + 1, dtype=jnp.int32) * s
         out = paged_ops.paged_attention_prefill(
-            q.reshape(b * s, hq, dk), k_pages[:, 0], v_pages[:, 0],
+            q.reshape(b * s, hq, dk), pool, layer,
             page_table, context_lens, qsl, query_lens.astype(jnp.int32),
             block_q=cfg.block_q, tile=cfg.tile, scale=scale,
         )
         return out.reshape(b, s, hq, -1)
-    k = gather_pages(k_pages, page_table)  # [B, Np*ps, Hkv, Dk]
-    v = gather_pages(v_pages, page_table)
+    k, v = _dense_kv(kv_pages, layer, page_table, dk)  # [B, Np*ps, Hkv, Dk]
     return _chunked_flash_xla(
         q, k, v, context_lens - query_lens, context_lens, scale=scale,
     )
@@ -322,8 +349,8 @@ _RAGGED_XLA_ROW_CHUNK = 64
 
 def _ragged_attention_xla(
     q: jax.Array,  # [T, Hq, Dk] token-packed
-    k_pages: jax.Array,
-    v_pages: jax.Array,
+    kv_pages: jax.Array,
+    layer,
     page_table: jax.Array,  # [S, Np]
     context_lens: jax.Array,  # [S]
     query_start_loc: jax.Array,  # [S+1]
@@ -343,7 +370,7 @@ def _ragged_attention_xla(
     hardware); the pallas path is the performance path."""
     t = q.shape[0]
     s = query_lens.shape[0]
-    require_single_pool(k_pages, "_ragged_attention_xla")
+    require_single_pool(kv_pages, "_ragged_attention_xla")
     tok = jnp.arange(t, dtype=jnp.int32)
     # owning sequence per token (vectorized binary search, paper §6.1);
     # out-of-range (padded) tokens clamp to the last row and mask dead
@@ -376,8 +403,7 @@ def _ragged_attention_xla(
     def one_chunk(args):
         qc, seq_c, qpos_c, kvlen_c = args
         pt_tok = page_table[seq_c]  # [chunk, Np]
-        k = gather_pages(k_pages, pt_tok)  # [chunk, Np*ps, Hkv, Dk]
-        v = gather_pages(v_pages, pt_tok)
+        k, v = _dense_kv(kv_pages, layer, pt_tok, q.shape[-1])
         return _chunked_flash_xla(
             qc[:, None], k, v, qpos_c, kvlen_c, scale=scale,
         )[:, 0]
@@ -394,8 +420,8 @@ def _ragged_attention_xla(
 def prefill_attention_ragged(
     backend: str,
     q: jax.Array,  # [T, Hq, Dk] token-packed
-    k_pages: jax.Array,
-    v_pages: jax.Array,
+    kv_pages: jax.Array,
+    layer,
     page_table: jax.Array,
     context_lens: jax.Array,
     query_start_loc: jax.Array,
@@ -412,14 +438,14 @@ def prefill_attention_ragged(
     routes q == 1 rows around the Q-Block machinery."""
     if backend == "xla":
         return _ragged_attention_xla(
-            q, k_pages, v_pages, page_table, context_lens,
+            q, kv_pages, layer, page_table, context_lens,
             query_start_loc, query_lens, scale=scale,
         )
+    pool = _local_pool(kv_pages, "prefill_attention_ragged[pallas]")
     cfg = heuristics.validate(
-        kernel_cfg or heuristics.KernelConfig("gqa"), k_pages.shape[3])
-    require_single_pool(k_pages, "prefill_attention_ragged[pallas]")
+        kernel_cfg or heuristics.KernelConfig("gqa"), pool.shape[3])
     return paged_ops.paged_attention_prefill(
-        q, k_pages[:, 0], v_pages[:, 0], page_table, context_lens,
+        q, pool, layer, page_table, context_lens,
         query_start_loc, query_lens, block_q=cfg.block_q, tile=cfg.tile,
         scale=scale,
     )
@@ -428,8 +454,8 @@ def prefill_attention_ragged(
 def unified_attention(
     backend: str,
     q: jax.Array,  # [T, Hq, Dk] token-packed, decode rows first
-    k_pages: jax.Array,
-    v_pages: jax.Array,
+    kv_pages: jax.Array,
+    layer,
     page_table: jax.Array,  # [S, Np]
     context_lens: jax.Array,  # [S]
     query_start_loc: jax.Array,  # [S+1]
@@ -458,14 +484,14 @@ def unified_attention(
     """
     if backend == "xla":
         return _ragged_attention_xla(
-            q, k_pages, v_pages, page_table, context_lens,
+            q, kv_pages, layer, page_table, context_lens,
             query_start_loc, query_lens, scale=scale,
         )
+    pool = _local_pool(kv_pages, "unified_attention[pallas]")
     cfg = heuristics.validate(
-        kernel_cfg or heuristics.KernelConfig("gqa"), k_pages.shape[3])
-    require_single_pool(k_pages, "unified_attention[pallas]")
+        kernel_cfg or heuristics.KernelConfig("gqa"), pool.shape[3])
     return paged_ops.paged_attention_unified(
-        q, k_pages[:, 0], v_pages[:, 0], page_table, context_lens,
+        q, pool, layer, page_table, context_lens,
         query_start_loc, query_lens, num_decode_seqs=num_decode_seqs,
         variant=cfg.variant, tile=cfg.tile, num_segments=cfg.num_segments,
         block_q=cfg.block_q, scale=scale,

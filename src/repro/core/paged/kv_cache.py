@@ -1,9 +1,16 @@
 """Paged KV cache pytrees + page read/write primitives.
 
 Layout (stacked across attention layers, leading dim L):
-  k_pages/v_pages : [L, Hkv, num_pools, pages_per_pool, page_size, D]
+  kv_pages        : [L, Hkv, num_pools, pages_per_pool, page_size, Dk + Dv]
+                    one row per token and KV head: K in the first Dk lanes,
+                    V in the last Dv (MLA: Dv = 0, V is a view of K), so a
+                    64-wide head still fills 128 TPU lanes
   page_table      : [S, pages_per_seq] int32, POOL-LOCAL page ids
   pool of seq s   : s // (S // num_pools)
+
+The pool is ONE array for the whole model.  A step carries it through the
+layer scan and writes each layer's new rows in place; readers take the
+whole stack plus a layer index, never a sliced-out layer.
 
 `num_pools` is the data-parallel degree: each DP shard owns one page pool
 and the sequences resident on it — pages are pooled (true PagedAttention
@@ -32,18 +39,18 @@ class ShardingError(ValueError):
     """
 
 
-def require_single_pool(k_pages: jax.Array, site: str):
-    """Paged kernels run on a shard-local pool view: [L?, Hkv, 1, P, ps, D].
+def require_single_pool(kv_pages: jax.Array, site: str):
+    """Paged kernels run on a shard-local pool view: [L, Hkv, 1, P, ps, W].
 
     The pool axis is the data-parallel degree; anything >1 must be split
     by the caller (shard_map / per-pool vmap) before reaching a kernel.
     """
-    pool_axis = k_pages.ndim - 4
-    if k_pages.shape[pool_axis] != 1:
+    pool_axis = kv_pages.ndim - 4
+    if kv_pages.shape[pool_axis] != 1:
         raise ShardingError(
             f"{site}: expected a shard-local single-pool KV view but got "
-            f"num_pools={k_pages.shape[pool_axis]} (k_pages shape "
-            f"{tuple(k_pages.shape)}); split the pool axis across the mesh "
+            f"num_pools={kv_pages.shape[pool_axis]} (kv_pages shape "
+            f"{tuple(kv_pages.shape)}); split the pool axis across the mesh "
             f"before launching the kernel"
         )
 
@@ -85,19 +92,13 @@ def shard_cache_specs(specs: dict, num_devices: int) -> dict:
 def make_kv_cache_specs(num_layers, num_kv_heads, num_pools, pages_per_pool,
                         page_size, k_dim, v_dim, dtype):
     """ShapeDtypeStruct specs — v_dim 0 means V is a view into the latent K
-    pages (MLA)."""
-    specs = {
-        "k_pages": jax.ShapeDtypeStruct(
+    rows (MLA)."""
+    return {
+        "kv_pages": jax.ShapeDtypeStruct(
             (num_layers, num_kv_heads, num_pools, pages_per_pool, page_size,
-             k_dim), dtype
+             k_dim + v_dim), dtype
         )
     }
-    if v_dim:
-        specs["v_pages"] = jax.ShapeDtypeStruct(
-            (num_layers, num_kv_heads, num_pools, pages_per_pool, page_size,
-             v_dim), dtype
-        )
-    return specs
 
 
 def physical_slots(page_table: jax.Array, positions: jax.Array,
@@ -112,29 +113,36 @@ def physical_slots(page_table: jax.Array, positions: jax.Array,
     return jnp.where(valid, phys, pages_per_pool * page_size)
 
 
-def write_pages(pages: jax.Array, new: jax.Array, slots: jax.Array):
-    """pages [Hkv, G, P, ps, D]; new [S, T, Hkv, D]; slots [S, T] pool-local
-    flat slots. S = G * B_loc. Batched (per-pool) scatter; out-of-range
-    slots dropped."""
-    hkv, g, p, ps, d = pages.shape
+def write_pages(pool: jax.Array, layer, new: jax.Array, slots: jax.Array):
+    """XLA scatter of new rows into layer `layer`: pool [L, Hkv, G, P, ps,
+    W]; new [S, T, Hkv, W]; slots [S, T] pool-local flat slots. S = G *
+    B_loc. Batched (per-pool) scatter; out-of-range slots dropped.  The
+    xla backend's write (the pallas backend writes in place with
+    `paged_attention.ops.write_rows`)."""
+    nl, hkv, g, p, ps, w = pool.shape
     s, t = slots.shape
     b_loc = s // g
-    flat = pages.reshape(hkv, g, p * ps, d)
-    upd = new.transpose(2, 0, 1, 3).reshape(hkv, g, b_loc * t, d)
+    flat = pool.reshape(nl, hkv, g, p * ps, w)
+    upd = new.transpose(2, 0, 1, 3).reshape(hkv, g, b_loc * t, w)
     slots3 = slots.reshape(g, b_loc * t)
     garr = jnp.broadcast_to(jnp.arange(g)[:, None], (g, b_loc * t))
-    flat = flat.at[:, garr, slots3, :].set(upd, mode="drop")
-    return flat.reshape(hkv, g, p, ps, d)
+    # scalar layer + [G, B*T] index arrays around a slice: the indexed
+    # dims lead the update, the head dim follows
+    flat = flat.at[layer, :, garr, slots3, :].set(
+        upd.transpose(1, 2, 0, 3), mode="drop")
+    return flat.reshape(pool.shape)
 
 
-def gather_pages(pages: jax.Array, page_table: jax.Array) -> jax.Array:
-    """[Hkv, G, P, ps, D] + [S, Np] -> [S, Np*ps, Hkv, D] dense per-seq KV.
-    Batched over pools: stays shard-local under GSPMD."""
-    hkv, g, p, ps, d = pages.shape
+def gather_pages(pool: jax.Array, layer, page_table: jax.Array) -> jax.Array:
+    """Layer `layer` of [L, Hkv, G, P, ps, W] + [S, Np] -> [S, Np*ps, Hkv,
+    W] dense per-seq rows. Batched over pools: stays shard-local under
+    GSPMD."""
+    pages = pool[layer]
+    hkv, g, p, ps, w = pages.shape
     s, np_ = page_table.shape
     b_loc = s // g
     pt = page_table.reshape(1, g, b_loc * np_, 1, 1)
     out = jnp.take_along_axis(pages[:, :, None], pt[..., None], axis=3)
-    # out: [Hkv, G, 1->B*Np broadcast, ...] -> [Hkv, G, B*Np, ps, D]
-    out = out.reshape(hkv, g, b_loc, np_, ps, d)
-    return out.transpose(1, 2, 3, 4, 0, 5).reshape(s, np_ * ps, hkv, d)
+    # out: [Hkv, G, 1->B*Np broadcast, ...] -> [Hkv, G, B*Np, ps, W]
+    out = out.reshape(hkv, g, b_loc, np_, ps, w)
+    return out.transpose(1, 2, 3, 4, 0, 5).reshape(s, np_ * ps, hkv, w)

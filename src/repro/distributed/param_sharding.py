@@ -99,7 +99,7 @@ def cache_spec(path: str, leaf, *, mesh: Mesh,
                model_axis: str = "model") -> P:
     """Serving-cache sharding.
 
-    KV pages  [L, Hkv, pools, P, ps, D]: pools on the batch axes (each DP
+    KV pool   [L, Hkv, pools, P, ps, Dk+Dv]: pools on the batch axes (each DP
         shard owns one pool), Hkv on the model axis when divisible;
     SSM state [L, B, ...]: B on the batch axes, the head dim on the model
         axis when divisible.
@@ -110,14 +110,14 @@ def cache_spec(path: str, leaf, *, mesh: Mesh,
     shape = leaf.shape
     model_n = mesh.shape[model_axis]
     data_n = _axes_size(mesh, batch_axes)
-    if "k_pages" in path or "v_pages" in path:
+    if "kv_pages" in path:
         spec = [None] * leaf.ndim
         if batch_axes and shape[2] % data_n == 0:
             spec[2] = tuple(batch_axes)
         if shape[1] % model_n == 0:
             spec[1] = model_axis  # prefer KV-head sharding (no score psum)
         elif KV_HEADDIM_SHARD and shape[-1] % model_n == 0:
-            # few KV heads (GQA/MLA): shard head_dim over 'model' — the
+            # few KV heads (GQA/MLA): shard the K|V row over 'model' — the
             # score contraction then carries a per-tile psum, but the cache
             # fits (llama3-405b decode_32k: 2.1 TB of KV). §Perf also
             # evaluates the replicated-within-pool alternative

@@ -1,14 +1,18 @@
 """Jitted wrappers around the paged-attention Pallas kernels.
 
 Responsibilities (all static-shaped, jit-friendly):
-  * TPU-alignment padding: head_dim -> multiple of 128 lanes, Q-block rows ->
+  * the pool is the whole stacked `kv_pages [L, Hkv, P, ps, 2*D]` (K in
+    the first D lanes of a row, V in the rest) plus a `layer` index; the
+    kernels read it as it is, tile by tile (C4: `tile` is decoupled from
+    the page size and may be any divisor that is a multiple of 8).
+  * TPU alignment: a head dim that is not a multiple of 128 lanes gets its
+    query zero-padded across the V lanes (see kernel.py); Q-block rows ->
     multiple of 8 sublanes (the paper's `tl.dot` padding lesson, §8).
-  * reshaping the paged cache [Hkv, P, ps, D] into the tile view
-    [Hkv, P, tiles_per_page, tile, D] (C4: `tile` is decoupled from the page
-    size and may be any divisor that is a multiple of 8).
   * Q packing for the GQA Q-Block layout (C2) and the prefill metadata
     (cumulative-Q-block tensor + vectorized binary search, paper §6.1).
   * variant plumbing: `baseline` / `gqa` / `segmented` (C1/C2/C3).
+  * `write_plan` / `write_rows`: the step's new rows into the pool, in
+    place.
 
 Interpret mode: `interpret=None` auto-selects True off-TPU so the same call
 sites run on CPU (tests) and TPU (deployment).
@@ -24,27 +28,28 @@ import jax.numpy as jnp
 from repro import kernels
 from repro.core.paged.kv_cache import ShardingError
 from repro.kernels.paged_attention import kernel as K
-from repro.utils.misc import cdiv, round_up
+from repro.utils.misc import cdiv
 
 LANE = 128
 SUBLANE = 8
 
 
-def _pad_head_dim(x: jax.Array, axis: int = -1) -> jax.Array:
-    d = x.shape[axis]
-    dp = round_up(d, LANE)
-    if dp == d:
-        return x
-    pad = [(0, 0)] * x.ndim
-    pad[axis] = (0, dp - d)
-    return jnp.pad(x, pad)
+def _pool_query(q: jax.Array, kv_pages: jax.Array) -> tuple[jax.Array, int]:
+    """(q as the kernels take it, split): a head dim that is a multiple of
+    128 lanes splits the pool row at D; any other is read as the whole
+    row, so q is zero-padded across the V lanes (q·row == q·k)."""
+    d, w = q.shape[-1], kv_pages.shape[-1]
+    if w != 2 * d:
+        raise ValueError(f"pool rows hold {w} lanes, expected K and V of "
+                         f"head_dim {d} ({2 * d})")
+    if d % LANE == 0:
+        return q, d
+    pad = [(0, 0)] * (q.ndim - 1) + [(0, w - d)]
+    return jnp.pad(q, pad), 0
 
 
-def _tile_view(pages: jax.Array, tile: int) -> jax.Array:
-    """[Hkv, P, ps, D] -> [Hkv, P, ps//tile, tile, D] (free reshape)."""
-    hkv, p, ps, d = pages.shape
-    assert ps % tile == 0, f"tile {tile} must divide page_size {ps}"
-    return pages.reshape(hkv, p, ps // tile, tile, d)
+def _layer(layer) -> jax.Array:
+    return jnp.asarray(layer, jnp.int32).reshape(1)
 
 
 def default_tile(page_size: int) -> int:
@@ -67,8 +72,8 @@ def default_tile(page_size: int) -> int:
 )
 def paged_attention_decode(
     q: jax.Array,  # [S, Hq, D]
-    k_pages: jax.Array,  # [Hkv, P, ps, D]
-    v_pages: jax.Array,
+    kv_pages: jax.Array,  # [L, Hkv, P, ps, 2*D]
+    layer: jax.Array,  # () int32
     page_table: jax.Array,  # [S, Np] int32
     context_lens: jax.Array,  # [S] int32
     *,
@@ -81,61 +86,52 @@ def paged_attention_decode(
     """Single-token decode over the paged KV cache. Returns [S, Hq, D]."""
     interpret = kernels.interpret_mode(interpret)
     s_, hq, d = q.shape
-    hkv, p, ps, dk = k_pages.shape
-    assert dk == d
+    _, hkv, p, ps, w = kv_pages.shape
     group = hq // hkv
     if scale is None:
         scale = 1.0 / (d**0.5)
     if tile is None:
         tile = default_tile(ps)
-    orig_d = d
-    q = _pad_head_dim(q)
-    k_pages = _pad_head_dim(k_pages)
-    v_pages = _pad_head_dim(v_pages)
-    d = q.shape[-1]
+    assert ps % tile == 0, f"tile {tile} must divide page_size {ps}"
+    q, split = _pool_query(q, kv_pages)
+    dq, dv = q.shape[-1], w - split
     tpp = ps // tile
     np_ = page_table.shape[1]
     num_tiles = np_ * tpp
-    kt = _tile_view(k_pages, tile)
-    vt = _tile_view(v_pages, tile)
+    layer = _layer(layer)
     page_table = page_table.astype(jnp.int32)
     context_lens = context_lens.astype(jnp.int32)
+    kw = dict(tile=tile, tiles_per_page=tpp, scale=scale, split=split,
+              interpret=interpret)
 
     if variant == "baseline":
         # paper §4.3: one (seq, q_head) per cell; each q head re-streams KV.
-        qq = q.reshape(s_, hq, 1, d)
+        qq = q.reshape(s_, hq, 1, dq)
         out = K.paged_decode(
-            qq, kt, vt, page_table, context_lens,
-            tile=tile, tiles_per_page=tpp, num_tiles=num_tiles,
-            kv_head_of_cell=lambda h: jax.lax.div(h, jnp.int32(group)),
-            scale=scale, interpret=interpret,
+            qq, kv_pages, layer, page_table, context_lens,
+            num_tiles=num_tiles,
+            kv_head_of_cell=lambda h: jax.lax.div(h, jnp.int32(group)), **kw,
         )
-        out = out.reshape(s_, hq, d)
     elif variant == "gqa":
         # paper §4.4: Q-Block = all q heads sharing a KV head.
-        qq = q.reshape(s_, hkv, group, d)
+        qq = q.reshape(s_, hkv, group, dq)
         out = K.paged_decode(
-            qq, kt, vt, page_table, context_lens,
-            tile=tile, tiles_per_page=tpp, num_tiles=num_tiles,
-            kv_head_of_cell=lambda h: h,
-            scale=scale, interpret=interpret,
+            qq, kv_pages, layer, page_table, context_lens,
+            num_tiles=num_tiles, kv_head_of_cell=lambda h: h, **kw,
         )
-        out = out.reshape(s_, hq, d)
     elif variant == "segmented":
         # paper §4.5: parallel tiled softmax + reduction kernel.
         nseg = min(num_segments, num_tiles)
         tps = cdiv(num_tiles, nseg)
-        qq = q.reshape(s_, hkv, group, d)
+        qq = q.reshape(s_, hkv, group, dq)
         o_seg, m_seg, l_seg = K.paged_decode_segmented(
-            qq, kt, vt, page_table, context_lens,
-            tile=tile, tiles_per_page=tpp, num_segments=nseg,
-            tiles_per_segment=tps, scale=scale, interpret=interpret,
+            qq, kv_pages, layer, page_table, context_lens,
+            num_segments=nseg, tiles_per_segment=tps, **kw,
         )
         out = K.segment_reduce(o_seg, m_seg, l_seg, q.dtype, interpret=interpret)
-        out = out.reshape(s_, hq, d)
     else:
         raise ValueError(f"unknown variant {variant!r}")
-    return out[..., :orig_d]
+    return out.reshape(s_, hq, dv)[..., dv - d:]
 
 
 # ---------------------------------------------------------------------------
@@ -195,8 +191,8 @@ def cdiv_arr(a: jax.Array, b: int) -> jax.Array:
 )
 def paged_attention_unified(
     q: jax.Array,  # [T, Hq, D] token-packed: decode rows first, then chunks
-    k_pages: jax.Array,  # [Hkv, P, ps, D]
-    v_pages: jax.Array,
+    kv_pages: jax.Array,  # [L, Hkv, P, ps, 2*D]
+    layer: jax.Array,  # () int32
     page_table: jax.Array,  # [S, Np]
     context_lens: jax.Array,  # [S]
     query_start_loc: jax.Array,  # [S+1]
@@ -237,13 +233,13 @@ def paged_attention_unified(
     parts = []
     if nd:
         parts.append(paged_attention_decode(
-            q[:nd], k_pages, v_pages, page_table[:nd], context_lens[:nd],
+            q[:nd], kv_pages, layer, page_table[:nd], context_lens[:nd],
             variant=variant, tile=tile, num_segments=num_segments,
             scale=scale, interpret=interpret,
         ))
     if t > nd:
         parts.append(paged_attention_prefill(
-            q[nd:], k_pages, v_pages, page_table[nd:], context_lens[nd:],
+            q[nd:], kv_pages, layer, page_table[nd:], context_lens[nd:],
             query_start_loc[nd:] - nd, query_lens[nd:],
             block_q=block_q, tile=tile, num_q_blocks=num_q_blocks,
             scale=scale, interpret=interpret,
@@ -257,8 +253,8 @@ def paged_attention_unified(
 )
 def paged_attention_prefill(
     q: jax.Array,  # [T, Hq, D]
-    k_pages: jax.Array,  # [Hkv, P, ps, D]
-    v_pages: jax.Array,
+    kv_pages: jax.Array,  # [L, Hkv, P, ps, 2*D]
+    layer: jax.Array,  # () int32
     page_table: jax.Array,  # [S, Np]
     context_lens: jax.Array,  # [S]
     query_start_loc: jax.Array,  # [S+1]
@@ -276,21 +272,19 @@ def paged_attention_prefill(
     with zeros in dead rows.
     """
     interpret = kernels.interpret_mode(interpret)
-    t, hq, d = q.shape
+    t, hq, orig_d = q.shape
     s_ = query_lens.shape[0]
-    hkv, p, ps, _ = k_pages.shape
+    _, hkv, p, ps, w = kv_pages.shape
     group = hq // hkv
     if scale is None:
-        scale = 1.0 / (d**0.5)
+        scale = 1.0 / (orig_d**0.5)
     if tile is None:
         tile = default_tile(ps)
+    assert ps % tile == 0, f"tile {tile} must divide page_size {ps}"
     if num_q_blocks is None:
         num_q_blocks = t // block_q + s_
-    orig_d = d
-    q = _pad_head_dim(q)
-    k_pages = _pad_head_dim(k_pages)
-    v_pages = _pad_head_dim(v_pages)
-    d = q.shape[-1]
+    q, split = _pool_query(q, kv_pages)
+    d, dv = q.shape[-1], w - split
     tpp = ps // tile
     np_ = page_table.shape[1]
     num_kv_tiles = np_ * tpp
@@ -318,18 +312,84 @@ def paged_attention_prefill(
     )
 
     o_packed = K.paged_prefill_qblock(
-        q_packed, _tile_view(k_pages, tile), _tile_view(v_pages, tile),
+        q_packed, kv_pages, _layer(layer),
         qb_seq, qb_pos0, page_table, context_lens,
         tile=tile, tiles_per_page=tpp, num_kv_tiles=num_kv_tiles,
-        block_q=block_q, group=group, scale=scale, interpret=interpret,
+        block_q=block_q, group=group, scale=scale, split=split,
+        interpret=interpret,
     )
 
     # ---- scatter back to [T, Hq, D]; dead rows -> dropped ----
-    o_packed = o_packed.reshape(num_q_blocks, hkv, block_q, group, d)
+    o_packed = o_packed[..., dv - orig_d:].reshape(
+        num_q_blocks, hkv, block_q, group, orig_d)
     o_packed = o_packed.transpose(0, 2, 1, 3, 4)  # [NQB, BQ, Hkv, G, D]
     scatter_rows = jnp.where(row_live, rows, t)  # OOB -> dropped
-    out = jnp.zeros((t + 1, hkv, group, d), q.dtype)
+    out = jnp.zeros((t + 1, hkv, group, orig_d), q.dtype)
     out = out.at[scatter_rows.reshape(-1)].set(
-        o_packed.reshape(num_q_blocks * block_q, hkv, group, d), mode="drop"
+        o_packed.reshape(num_q_blocks * block_q, hkv, group, orig_d),
+        mode="drop"
     )
-    return out[:t].reshape(t, hq, d)[..., :orig_d]
+    return out[:t].reshape(t, hq, orig_d)
+
+
+# ---------------------------------------------------------------------------
+# In-place pool update
+# ---------------------------------------------------------------------------
+
+
+def write_plan(slots: jax.Array, page_size: int, num_pages: int) -> dict:
+    """Where a step's new rows go, computed once per step and shared by
+    every layer's `write_rows`.
+
+    slots [T]: each row's pool slot (page * page_size + offset); a slot
+    outside the pool (the trash slot) drops its row.  Rows of one write
+    block must be adjacent in `slots` and fill consecutive slots: each
+    sequence writes one run of positions, which every packed, padded and
+    speculative layout does."""
+    t = slots.shape[0]
+    br = K.write_block_rows(page_size)
+    slots = slots.astype(jnp.int32)
+    live = (slots >= 0) & (slots < num_pages * page_size)
+    blk = jnp.where(live, slots // br, -1)
+    prev = jnp.concatenate([jnp.full((1,), -1, jnp.int32), blk[:-1]])
+    first = live & (blk != prev)
+    (start,) = jnp.nonzero(first, size=t, fill_value=0)
+    start = start.astype(jnp.int32)
+    block_of_row = jnp.where(live, jnp.cumsum(first) - 1, t)
+    count = jax.ops.segment_sum(live.astype(jnp.int32), block_of_row,
+                                num_segments=t + 1)[:t]
+    s0 = slots[start]
+    r0 = s0 % br
+    return {
+        "page": s0 // page_size,
+        "blk": (s0 % page_size) // br,
+        "src": start - r0 + br,  # staged rows carry br rows in front
+        "r0": r0,
+        "r1": r0 + count,
+        "num_blocks": jnp.sum(first).astype(jnp.int32),
+    }
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def write_rows(
+    kv_pages: jax.Array,  # [L, Hkv, P, ps, W]
+    layer: jax.Array,  # () int32
+    rows: jax.Array,  # [T, Hkv, W] the step's new rows
+    plan: dict,  # write_plan(slots) for the same T rows
+    *,
+    interpret: bool | None = None,
+) -> jax.Array:
+    """`kv_pages` with the step's rows written at their slots, in place:
+    only the write blocks that receive a row are read and rewritten."""
+    interpret = kernels.interpret_mode(interpret)
+    t, hkv, w = rows.shape
+    br = K.write_block_rows(kv_pages.shape[3])
+    lanes = LANE if w % LANE == 0 else w
+    nc = w // lanes
+    staged = rows.astype(jnp.float32).reshape(t, hkv, nc, lanes)
+    staged = staged.transpose(1, 2, 0, 3).reshape(hkv * nc, t, lanes)
+    staged = jnp.pad(staged, ((0, 0), (br, br), (0, 0)))
+    return K.kv_write(
+        kv_pages, staged, _layer(layer), plan["page"], plan["blk"],
+        plan["src"], plan["r0"], plan["r1"], plan["num_blocks"],
+        interpret=interpret)

@@ -1,7 +1,10 @@
 """Pure-jnp oracles for the paged attention kernels.
 
 Layout conventions (shared with kernel.py / ops.py):
-  k_pages, v_pages : [num_kv_heads, num_pages, page_size, head_dim]
+  kv_pages         : [num_layers, num_kv_heads, num_pages, page_size,
+                     2 * head_dim]: one row per token and KV head, K in its
+                     first head_dim lanes and V in the rest; every function
+                     takes the whole stack plus a `layer` index
   page_table       : [max_seqs, pages_per_seq] int32 (0-padded; entry j holds
                      the physical page of logical page j of that sequence)
   context_lens     : [max_seqs] int32 — number of *valid* tokens in the cache
@@ -29,18 +32,30 @@ import jax.numpy as jnp
 _NEG_INF = -0.7 * float(jnp.finfo(jnp.float32).max)
 
 
-def gather_pages(pages: jax.Array, page_table: jax.Array) -> jax.Array:
-    """[Hkv, P, ps, D] + [S, Np] -> [S, Np*ps, Hkv, D] (dense per-seq KV)."""
-    # pages[h, page_table[s, j]] for all s, j
-    g = pages[:, page_table]  # [Hkv, S, Np, ps, D]
-    hkv, s, np_, ps, d = g.shape
-    return g.transpose(1, 2, 3, 0, 4).reshape(s, np_ * ps, hkv, d)
+def gather_kv(kv_pages: jax.Array, layer, page_table: jax.Array, d: int):
+    """Layer `layer` of [L, Hkv, P, ps, 2D] + [S, Np] -> dense per-seq
+    (k, v), each [S, Np*ps, Hkv, D]."""
+    g = kv_pages[layer][:, page_table]  # [Hkv, S, Np, ps, 2D]
+    hkv, s, np_, ps, w = g.shape
+    kv = g.transpose(1, 2, 3, 0, 4).reshape(s, np_ * ps, hkv, w)
+    return kv[..., :d], kv[..., d:]
+
+
+def write_rows_ref(kv_pages: jax.Array, layer, rows: jax.Array,
+                   slots: jax.Array) -> jax.Array:
+    """Oracle of the in-place write: rows [T, Hkv, W] at flat slots [T]
+    (page * page_size + offset) of layer `layer`; slots outside the pool
+    drop their row."""
+    nl, hkv, p, ps, w = kv_pages.shape
+    flat = kv_pages.reshape(nl, hkv, p * ps, w)
+    flat = flat.at[layer, :, slots].set(rows, mode="drop")
+    return flat.reshape(kv_pages.shape)
 
 
 def paged_attention_decode_ref(
     q: jax.Array,
-    k_pages: jax.Array,
-    v_pages: jax.Array,
+    kv_pages: jax.Array,
+    layer,
     page_table: jax.Array,
     context_lens: jax.Array,
     *,
@@ -48,12 +63,11 @@ def paged_attention_decode_ref(
 ) -> jax.Array:
     """Oracle for single-token decode over the paged cache."""
     s_, hq, d = q.shape
-    hkv = k_pages.shape[0]
+    hkv = kv_pages.shape[1]
     group = hq // hkv
     if scale is None:
         scale = 1.0 / (d**0.5)
-    k = gather_pages(k_pages, page_table)  # [S, L, Hkv, D]
-    v = gather_pages(v_pages, page_table)
+    k, v = gather_kv(kv_pages, layer, page_table, d)  # [S, L, Hkv, D]
     length = k.shape[1]
     qf = q.astype(jnp.float32).reshape(s_, hkv, group, d)
     kf = k.astype(jnp.float32)
@@ -71,8 +85,8 @@ def paged_attention_decode_ref(
 
 def paged_attention_prefill_ref(
     q: jax.Array,
-    k_pages: jax.Array,
-    v_pages: jax.Array,
+    kv_pages: jax.Array,
+    layer,
     page_table: jax.Array,
     context_lens: jax.Array,
     query_start_loc: jax.Array,
@@ -87,12 +101,11 @@ def paged_attention_prefill_ref(
     """
     t, hq, d = q.shape
     s_ = query_lens.shape[0]
-    hkv = k_pages.shape[0]
+    hkv = kv_pages.shape[1]
     group = hq // hkv
     if scale is None:
         scale = 1.0 / (d**0.5)
-    k = gather_pages(k_pages, page_table)  # [S, L, Hkv, D]
-    v = gather_pages(v_pages, page_table)
+    k, v = gather_kv(kv_pages, layer, page_table, d)  # [S, L, Hkv, D]
     length = k.shape[1]
 
     # map each token row -> (seq idx, abs position); dead rows -> seq 0, pos -1
@@ -139,36 +152,3 @@ def merge_segments_ref(
     o_tot = jnp.sum(o_seg * w[..., None], axis=-3)  # [..., G, D]
     l_safe = jnp.where(l_tot == 0.0, 1.0, l_tot)
     return o_tot / l_safe[..., None]
-
-
-def write_kv_to_pages(
-    k_pages: jax.Array,
-    v_pages: jax.Array,
-    k_new: jax.Array,
-    v_new: jax.Array,
-    page_table: jax.Array,
-    slot_positions: jax.Array,
-    valid: jax.Array,
-):
-    """Scatter new KV rows into the paged cache (oracle path).
-
-    k_new/v_new: [T, Hkv, D]; slot_positions: [T] absolute position in the
-    owning sequence; valid: [T] bool; page_table rows indexed by seq_of_row.
-    This variant takes pre-resolved physical slots: slot = page * ps + off.
-    """
-    ps = k_pages.shape[2]
-    page = slot_positions // ps
-    off = slot_positions % ps
-    phys = jnp.where(valid, page_table[jnp.arange(len(page)), page], 0)
-    # guard invalid rows by directing them to a trash slot via clamping +
-    # predicated writes (set mode drops out-of-range)
-    hkv = k_pages.shape[0]
-    phys = jnp.where(valid, phys, k_pages.shape[1])  # OOB -> dropped
-    kp = k_pages.at[:, phys, off, :].set(
-        k_new.transpose(1, 0, 2), mode="drop"
-    )
-    vp = v_pages.at[:, phys, off, :].set(
-        v_new.transpose(1, 0, 2), mode="drop"
-    )
-    del hkv
-    return kp, vp

@@ -28,6 +28,7 @@ from repro.core.paged.kv_cache import (
 )
 from repro.distributed.sharding import constrain
 from repro.kernels.flash_attention.ref import flash_attention_xla
+from repro.kernels.paged_attention import ops as paged_ops
 from repro.models import layers as L
 
 
@@ -94,6 +95,31 @@ def _qkv(cfg: ModelConfig, p, x, positions):
     return q, k, v
 
 
+def kv_write_meta(cfg: ModelConfig, meta: dict, positions, *, mode: str,
+                  pool: jax.Array, backend: str) -> dict:
+    """`meta` plus where this step's KV rows land, computed once per step
+    and shared by every layer: `slots` [B, S] pool-local flat slots (the
+    trash slot for rows that write nothing) and, on the pallas backend,
+    the in-place write plan over them."""
+    pt = meta["page_table"]
+    num_pages, ps = pool.shape[3], pool.shape[4]
+    if mode == "unified":
+        slots = meta["slot_mapping"]
+    else:
+        pos_abs = positions if positions.ndim == 2 else positions[0]
+        if mode == "decode":
+            valid = (pos_abs >= 0) & (meta["context_lens"][:, None] > 0)
+        else:
+            s = pos_abs.shape[1]
+            valid = jnp.arange(s)[None, :] < meta["query_lens"][:, None]
+        slots = physical_slots(pt, pos_abs, valid, ps, num_pages)
+    meta = dict(meta, slots=slots)
+    if backend == "pallas" and not cfg.mla.kv_lora_rank:
+        meta["write_plan"] = paged_ops.write_plan(slots.reshape(-1), ps,
+                                                  num_pages)
+    return meta
+
+
 def _local_heads(x, n_local: int, axis_name: str):
     """This device's contiguous head block of a [B, S, H, D] projection:
     block `axis_index` of the full head set every device projects (the
@@ -104,12 +130,16 @@ def _local_heads(x, n_local: int, axis_name: str):
 
 
 def attention(cfg: ModelConfig, p, x, positions, *, mode: str,
-              cache=None, meta=None, backend: str = "xla",
+              cache=None, layer=None, meta=None, backend: str = "xla",
               kernel_cfg=None, shard=None):
     """x [B, S, d]. Returns (out [B, S, d], new_cache_or_None).
 
-    cache: {'k_pages': [Hkv,P,ps,Dk], 'v_pages': ...} for this layer.
-    meta:  {'page_table', 'context_lens', 'query_lens'} (serve modes).
+    cache: {'kv_pages': [L, Hkv, G, P, ps, Dk+Dv]}, the whole stacked
+    pool; `layer` (int or traced scalar) is the one this call writes and
+    reads.  The returned cache is the same pool with this layer's rows
+    written (in place on the pallas backend).
+    meta:  {'page_table', 'context_lens', 'query_lens', ...} (serve modes)
+    with `kv_write_meta`'s slots.
     kernel_cfg: static heuristics.KernelConfig chosen at dispatch time
     (None -> the backend's default); selects the paged-kernel variant /
     tile / segments, so it must be part of the engine's executable key.
@@ -124,7 +154,7 @@ def attention(cfg: ModelConfig, p, x, positions, *, mode: str,
                 "MLA attention has a single latent KV head and cannot be "
                 f"head-sharded (requested tp={shard.size})")
         return _mla_attention(cfg, p, x, positions, mode=mode, cache=cache,
-                              meta=meta, backend=backend)
+                              layer=layer, meta=meta, backend=backend)
     b, s, _ = x.shape
     dh = cfg.resolved_head_dim
     q, k, v = _qkv(cfg, p, x, positions)
@@ -148,16 +178,18 @@ def attention(cfg: ModelConfig, p, x, positions, *, mode: str,
     else:
         pt = meta["page_table"]
         ctx = meta["context_lens"]
-        num_pages, ps = cache["k_pages"].shape[2], cache["k_pages"].shape[3]
+        # this layer's new K|V rows land at their slots (trash slot for
+        # padded tokens) before the attention reads the pool
+        pool = attn_backend.write_kv(
+            backend, cache["kv_pages"], layer,
+            jnp.concatenate([k, v], axis=-1), meta["slots"],
+            meta.get("write_plan"))
         if mode == "unified":
             # token-packed step: x is [1, T, d] with per-token absolute
-            # positions (already rope'd above); each token's KV row lands
-            # at the host-computed slot (trash slot for padded tokens),
-            # then ONE ragged launch covers decode rows + every chunk.
-            kp = write_pages(cache["k_pages"], k, meta["slot_mapping"])
-            vp = write_pages(cache["v_pages"], v, meta["slot_mapping"])
+            # positions (already rope'd above); ONE ragged launch covers
+            # decode rows + every chunk.
             o = attn_backend.unified_attention(
-                backend, q[0], kp, vp, pt, ctx,
+                backend, q[0], pool, layer, pt, ctx,
                 meta["query_start_loc"], meta["query_lens"],
                 num_decode_seqs=meta["num_decode_seqs"], scale=scale,
                 kernel_cfg=kernel_cfg,
@@ -167,42 +199,28 @@ def attention(cfg: ModelConfig, p, x, positions, *, mode: str,
                 # contiguous head blocks, so a tiled all-gather on the
                 # head axis reassembles exactly the single-device o
                 o = jax.lax.all_gather(o, shard.axis, axis=2, tiled=True)
-            new_cache = {"k_pages": kp, "v_pages": vp}
-        elif mode in ("prefill", "prefill_cached"):
-            qlens = meta["query_lens"]
-            pos_abs = positions if positions.ndim == 2 else positions[0]
-            valid = (jnp.arange(s)[None, :] < qlens[:, None])
-            slots = physical_slots(pt, pos_abs, valid, ps, num_pages)
-            kp = write_pages(cache["k_pages"], k, slots)
-            vp = write_pages(cache["v_pages"], v, slots)
-            if mode == "prefill_cached":
-                # prefix-cache resume: positions are offset by the cached
-                # context (context_lens = cached + chunk); attend over the
-                # pages, which hold the shared prefix + the chunk just
-                # written above.
-                o = attn_backend.prefill_attention_cached(
-                    backend, q, qlens, kp, vp, pt, ctx, scale=scale,
-                    kernel_cfg=kernel_cfg,
-                )
-            else:
-                o = attn_backend.prefill_attention_uniform(
-                    backend, q, k, v, qlens, kp, vp, pt, ctx, scale=scale,
-                    kernel_cfg=kernel_cfg,
-                )
-            new_cache = {"k_pages": kp, "v_pages": vp}
+        elif mode == "prefill_cached":
+            # prefix-cache resume: positions are offset by the cached
+            # context (context_lens = cached + chunk); attend over the
+            # pages, which hold the shared prefix + the chunk just
+            # written above.
+            o = attn_backend.prefill_attention_cached(
+                backend, q, meta["query_lens"], pool, layer, pt, ctx,
+                scale=scale, kernel_cfg=kernel_cfg,
+            )
+        elif mode == "prefill":
+            o = attn_backend.prefill_attention_uniform(
+                backend, q, k, v, meta["query_lens"], pool, layer, pt, ctx,
+                scale=scale, kernel_cfg=kernel_cfg,
+            )
         elif mode == "decode":
-            pos_abs = positions if positions.ndim == 2 else positions[0]
-            valid = (pos_abs >= 0) & (ctx[:, None] > 0)
-            slots = physical_slots(pt, pos_abs, valid, ps, num_pages)
-            kp = write_pages(cache["k_pages"], k, slots)
-            vp = write_pages(cache["v_pages"], v, slots)
             o = attn_backend.decode_attention(
-                backend, q[:, 0], kp, vp, pt, ctx, scale=scale,
+                backend, q[:, 0], pool, layer, pt, ctx, scale=scale,
                 kernel_cfg=kernel_cfg, blockscan=cfg.decode_blockscan,
             )[:, None]
-            new_cache = {"k_pages": kp, "v_pages": vp}
         else:
             raise ValueError(mode)
+        new_cache = {"kv_pages": pool}
 
     o = constrain(o, "batch", "seq", "heads", "head_dim")
     out = L.linear(p["wo"], o.reshape(b, s, -1).astype(x.dtype))
@@ -341,8 +359,8 @@ def _mla_prefill_fused(cfg, p, q_nope, q_rope, latent, k_rope, qlens, *,
     return out.astype(q_nope.dtype)
 
 
-def _mla_attention(cfg: ModelConfig, p, x, positions, *, mode, cache, meta,
-                   backend):
+def _mla_attention(cfg: ModelConfig, p, x, positions, *, mode, cache, layer,
+                   meta, backend):
     m = cfg.mla
     b, s, _ = x.shape
     h = cfg.num_q_heads
@@ -351,15 +369,15 @@ def _mla_attention(cfg: ModelConfig, p, x, positions, *, mode, cache, meta,
     latent, k_rope = _mla_latent(cfg, p, x, positions)
     new_cache = None
 
-    if mode == "prefill" and cfg.mla_fused_prefill:
-        pt, ctx, qlens = (meta["page_table"], meta["context_lens"],
-                          meta["query_lens"])
-        num_pages, ps = cache["k_pages"].shape[2], cache["k_pages"].shape[3]
-        pos_abs = positions if positions.ndim == 2 else positions[0]
-        valid = jnp.arange(s)[None, :] < qlens[:, None]
-        slots = physical_slots(pt, pos_abs, valid, ps, num_pages)
+    if mode in ("prefill", "decode"):
+        # MLA caches the compressed [latent | k_rope] row (one 'KV head');
+        # its paged reads run on the xla path, so its writes do too
         kv_row = jnp.concatenate([latent, k_rope], axis=-1)[:, :, None, :]
-        new_cache = {"k_pages": write_pages(cache["k_pages"], kv_row, slots)}
+        new_cache = {"kv_pages": write_pages(cache["kv_pages"], layer,
+                                             kv_row, meta["slots"])}
+
+    if mode == "prefill" and cfg.mla_fused_prefill:
+        qlens = meta["query_lens"]
         o = _mla_prefill_fused(cfg, p, q_nope, q_rope, latent, k_rope,
                                qlens, scale=scale)
         out = L.linear(p["wo"], o.reshape(b, s, -1).astype(x.dtype))
@@ -381,18 +399,9 @@ def _mla_attention(cfg: ModelConfig, p, x, positions, *, mode, cache, meta,
         while s % kv_block:
             kv_block //= 2
         if mode == "prefill":
-            # cache the compressed [latent | k_rope] rows (one 'KV head')
-            pt, ctx, qlens = (meta["page_table"], meta["context_lens"],
-                              meta["query_lens"])
-            num_pages, ps = cache["k_pages"].shape[2], cache["k_pages"].shape[3]
-            pos_abs = positions if positions.ndim == 2 else positions[0]
-            valid = jnp.arange(s)[None, :] < qlens[:, None]
-            slots = physical_slots(pt, pos_abs, valid, ps, num_pages)
-            kv_row = jnp.concatenate([latent, k_rope], axis=-1)[:, :, None, :]
-            kp = write_pages(cache["k_pages"], kv_row, slots)
-            new_cache = {"k_pages": kp}
             o = flash_attention_xla(q, k, v, causal=True, scale=scale,
-                                    kv_block=kv_block, kv_len=qlens)
+                                    kv_block=kv_block,
+                                    kv_len=meta["query_lens"])
         else:
             o = flash_attention_xla(q, k, v, causal=True, scale=scale,
                                     kv_block=kv_block)
@@ -400,13 +409,6 @@ def _mla_attention(cfg: ModelConfig, p, x, positions, *, mode, cache, meta,
         # absorbed form: queries move into the latent space; the paged cache
         # is MQA over the 576-dim compressed rows
         pt, ctx = meta["page_table"], meta["context_lens"]
-        num_pages, ps = cache["k_pages"].shape[2], cache["k_pages"].shape[3]
-        pos_abs = positions if positions.ndim == 2 else positions[0]
-        valid = (pos_abs >= 0) & (ctx[:, None] > 0)
-        slots = physical_slots(pt, pos_abs, valid, ps, num_pages)
-        kv_row = jnp.concatenate([latent, k_rope], axis=-1)[:, :, None, :]
-        kp = write_pages(cache["k_pages"], kv_row, slots)
-        new_cache = {"k_pages": kp}
         wkb = p["wk_b"]["w"].reshape(m.kv_lora_rank, h, m.qk_nope_dim)
         q_abs = jnp.einsum("bshn,rhn->bshr", q_nope.astype(jnp.float32),
                            wkb.astype(jnp.float32))
@@ -414,8 +416,9 @@ def _mla_attention(cfg: ModelConfig, p, x, positions, *, mode, cache, meta,
             [q_abs.astype(x.dtype), q_rope], axis=-1
         )  # [B,1,H, r+rope]
         o_lat = attn_backend.decode_attention(
-            "xla", q_eff[:, 0], kp, None, pt, ctx, scale=scale,
-            v_dim=m.kv_lora_rank, blockscan=cfg.decode_blockscan,
+            "xla", q_eff[:, 0], new_cache["kv_pages"], layer, pt, ctx,
+            scale=scale, v_dim=m.kv_lora_rank,
+            blockscan=cfg.decode_blockscan,
         )  # [B, H, r]
         wvb = p["wv_b"]["w"].reshape(m.kv_lora_rank, h, m.v_head_dim)
         o = jnp.einsum("bhr,rhv->bhv", o_lat.astype(jnp.float32),

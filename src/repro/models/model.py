@@ -35,7 +35,9 @@ from repro.distributed.sharding import constrain
 from repro.models import layers as L
 from repro.models import sampling
 from repro.models import ssm_blocks as S
-from repro.models.attention import attention, init_attention, kv_cache_dims
+from repro.models.attention import (
+    attention, init_attention, kv_cache_dims, kv_write_meta,
+)
 from repro.models.moe import (
     init_moe, moe_ffn, moe_ffn_dropless, moe_ffn_dropless_ep,
 )
@@ -223,11 +225,11 @@ def make_cache(cfg: ModelConfig, *, max_seqs: int, num_pages: int,
 # ---------------------------------------------------------------------------
 
 
-def _decoder_block(cfg, p, x, positions, *, mode, cache, meta, backend,
-                   kernel_cfg=None, shard=None):
+def _decoder_block(cfg, p, x, positions, *, mode, cache, layer, meta,
+                   backend, kernel_cfg=None, shard=None):
     h, new_cache = attention(
         cfg, p["attn"], L.rms_norm(p["ln1"], x, cfg.norm_eps), positions,
-        mode=mode, cache=cache, meta=meta, backend=backend,
+        mode=mode, cache=cache, layer=layer, meta=meta, backend=backend,
         kernel_cfg=kernel_cfg, shard=shard,
     )
     x = x + h
@@ -268,61 +270,57 @@ def _head(cfg, params, x):
 
 def forward(cfg: ModelConfig, params, inputs, positions, *, mode: str,
             cache=None, meta=None, backend: str = "xla", kernel_cfg=None,
-            shard=None):
-    """Returns (logits [B,S,V] fp32, new_cache, aux_loss).  `kernel_cfg`
+            shard=None, head: bool = True):
+    """Returns (logits [B,S,V] fp32, new_cache, aux_loss); with
+    `head=False` the final hidden states [B,S,d] stand in for the logits,
+    for a caller that projects only the rows it needs.  `kernel_cfg`
     (a heuristics.KernelConfig or None) is STATIC dispatch metadata —
     chosen host-side per launch, baked into the traced program.  `shard`
     (a sharding.ShardCtx or None) marks a per-device invocation inside
     the serving mesh executor's shard_map; only the attention head axis
-    is sharded, everything else runs replicated."""
+    is sharded, everything else runs replicated.
+
+    The attention cache is one stacked pool; it is carried through the
+    layer loop (never passed as per-layer xs/ys), and each layer writes
+    its rows into it at its own index, so a donated pool is updated in
+    place."""
     x = _embed_inputs(cfg, params, inputs)
     meta = meta or {}
     aux_total = jnp.zeros((), jnp.float32)
     new_cache: dict[str, Any] = {}
     remat = mode == "train"
+    attn = (cache or {}).get("attn")
+    if attn is not None:
+        meta = kv_write_meta(cfg, meta, positions, mode=mode,
+                             pool=attn["kv_pages"], backend=backend)
 
     if cfg.family in ("dense", "moe", "audio", "vlm"):
         lead, _ = _moe_layout(cfg)
-        attn_cache = (cache or {}).get("attn")
-        layer_off = 0
-        for lp in params.get("lead_blocks", []):
-            c_l = (jax.tree.map(lambda t: t[layer_off], attn_cache)
-                   if attn_cache is not None else None)
-            x, nc, aux = _decoder_block(cfg, lp, x, positions, mode=mode,
-                                        cache=c_l, meta=meta, backend=backend,
-                                        kernel_cfg=kernel_cfg, shard=shard)
+        for i, lp in enumerate(params.get("lead_blocks", [])):
+            x, attn, aux = _decoder_block(cfg, lp, x, positions, mode=mode,
+                                          cache=attn, layer=i, meta=meta,
+                                          backend=backend,
+                                          kernel_cfg=kernel_cfg, shard=shard)
             aux_total += aux
-            if nc is not None:
-                new_cache.setdefault("_lead", []).append(nc)
-            layer_off += 1
 
         def body(carry, per_layer):
-            x, aux = carry
-            p_l, c_l = per_layer
-            x, nc, a = _decoder_block(cfg, p_l, x, positions, mode=mode,
-                                      cache=c_l, meta=meta, backend=backend,
-                                      kernel_cfg=kernel_cfg, shard=shard)
-            return (x, aux + a), nc
+            x, aux, attn = carry
+            p_l, layer = per_layer
+            x, attn, a = _decoder_block(cfg, p_l, x, positions, mode=mode,
+                                        cache=attn, layer=layer, meta=meta,
+                                        backend=backend,
+                                        kernel_cfg=kernel_cfg, shard=shard)
+            return (x, aux + a, attn), None
 
         if remat:
             body = jax.checkpoint(body)
-        scan_cache = (
-            jax.tree.map(lambda t: t[layer_off:], attn_cache)
-            if attn_cache is not None else None
+        n_scan = jax.tree.leaves(params["blocks"])[0].shape[0]
+        layers = lead + jnp.arange(n_scan, dtype=jnp.int32)
+        (x, aux_total, attn), _ = _scan(
+            body, (x, aux_total, attn), (params["blocks"], layers)
         )
-        (x, aux_total), nc_stack = _scan(
-            body, (x, aux_total), (params["blocks"], scan_cache)
-        )
-        if nc_stack is not None and attn_cache is not None:
-            lead_stack = new_cache.pop("_lead", [])
-            if lead_stack:
-                nc_stack = jax.tree.map(
-                    lambda lead0, rest: jnp.concatenate(
-                        [jnp.stack([lead0]), rest], axis=0
-                    ),
-                    lead_stack[0], nc_stack,
-                )
-            new_cache["attn"] = nc_stack
+        if attn is not None:
+            new_cache["attn"] = attn
 
     elif cfg.family == "hybrid":
         x, new_cache, aux_total = _hybrid_forward(
@@ -337,7 +335,8 @@ def forward(cfg: ModelConfig, params, inputs, positions, *, mode: str,
     else:
         raise ValueError(cfg.family)
 
-    return _head(cfg, params, x), (new_cache or None), aux_total
+    out = _head(cfg, params, x) if head else x
+    return out, (new_cache or None), aux_total
 
 
 def _serve_masks(mode, meta, b, s):
@@ -374,7 +373,7 @@ def _hybrid_forward(cfg, params, x, positions, *, mode, cache, meta, backend,
     def mamba_slice(tree, lo, hi):
         return jax.tree.map(lambda t: t[lo:hi], tree)
 
-    new_m, new_a = [], []
+    new_m = []
     off = 0
     for g in range(n_attn):
         xs = (
@@ -385,13 +384,11 @@ def _hybrid_forward(cfg, params, x, positions, *, mode, cache, meta, backend,
         x, nc = _scan(mamba_body, x, xs)
         new_m.append(nc)
         off += group
-        c_l = (jax.tree.map(lambda t: t[g], a_cache)
-               if a_cache is not None else None)
-        x, nca, a = _decoder_block(cfg, params["shared"], x, positions,
-                                   mode=mode, cache=c_l, meta=meta,
-                                   backend=backend, kernel_cfg=kernel_cfg)
+        x, a_cache, a = _decoder_block(cfg, params["shared"], x, positions,
+                                       mode=mode, cache=a_cache, layer=g,
+                                       meta=meta, backend=backend,
+                                       kernel_cfg=kernel_cfg)
         aux += a
-        new_a.append(nca)
     if off < n_mamba:  # tail
         xs = (
             mamba_slice(params["mamba"], off, n_mamba),
@@ -406,9 +403,7 @@ def _hybrid_forward(cfg, params, x, positions, *, mode, cache, meta, backend,
         new_cache["mamba"] = jax.tree.map(
             lambda *ts: jnp.concatenate(ts, 0), *new_m
         )
-        new_cache["attn"] = jax.tree.map(
-            lambda *ts: jnp.stack(ts, 0), *new_a
-        )
+        new_cache["attn"] = a_cache
     return x, new_cache, aux
 
 
@@ -613,18 +608,20 @@ def apply_unified(cfg: ModelConfig, params, cache, batch, *, backend="xla",
         src = batch["token_source"]
         inputs = jnp.where(src >= 0,
                            batch["prev_tokens"][jnp.clip(src, 0)], inputs)
-    logits, new_cache, _ = forward(
+    hidden, new_cache, _ = forward(
         cfg, params, inputs, batch["positions"], mode="unified",
         cache=cache, meta=meta, backend=backend, kernel_cfg=kernel_cfg,
-        shard=shard,
+        shard=shard, head=False,
     )
-    # per-sequence last-token rows of the packed stream ([1, T, V] ->
-    # [S, V]); 0-length (padded) rows clamp to their segment start — the
-    # engine never reads them
+    t = hidden.shape[1]
+    # per-sequence last-token rows of the packed stream; only these rows
+    # (or the verify rows below) go through the LM head, never all T;
+    # 0-length (padded) rows clamp to their segment start — the engine
+    # never reads them
     last = batch["query_start_loc"][:-1] + jnp.clip(
         batch["query_lens"] - 1, 0)
-    last = jnp.minimum(last, logits.shape[1] - 1)
-    last_logits = logits[0, last]
+    last = jnp.minimum(last, t - 1)
+    last_logits = _head(cfg, params, hidden[:, last])[0]
     if not sample:
         return last_logits, new_cache
     if max_draft == 0:
@@ -648,8 +645,8 @@ def apply_unified(cfg: ModelConfig, params, cache, batch, *, backend="xla",
     start = batch["query_start_loc"][:-1]
     pos = last[:, None] - spec[:, None] + offs[None, :]     # [S, K+1]
     pos = jnp.clip(pos, start[:, None], last[:, None])
-    pos = jnp.clip(pos, 0, logits.shape[1] - 1)
-    ver_logits = logits[0, pos]                             # [S, K+1, V]
+    pos = jnp.clip(pos, 0, t - 1)
+    ver_logits = _head(cfg, params, hidden[0, pos])         # [S, K+1, V]
     # per-position keys at counters num_generated + j: the exact fold
     # sequence sequential decoding would use for these draws
     rep = lambda a: jnp.repeat(a, K + 1)

@@ -110,6 +110,7 @@ import contextlib
 import dataclasses
 import functools
 import logging
+import math
 import time
 from typing import Sequence
 
@@ -318,8 +319,18 @@ class Engine:
                                prefix_cache=self.prefix_cache,
                                enable_chunked_prefill=enable_chunked_prefill,
                                telemetry=telemetry, drafter=self.drafter)
+        # the live cache: every launch DONATES the cache it is given and
+        # returns the next one (the KV pool is updated in place), so
+        # nothing may hold on to a launch's input after the launch
         self.cache = self.executor.place_cache(
             M.make_cache(cfg, max_seqs=max_seqs, num_pages=num_pages))
+        # bytes of the KV pool on one device, and per unified executable
+        # the input bytes its compile aliases to outputs (memory_analysis):
+        # a steady engine shows every one aliasing the whole pool
+        self.kv_pool_bytes = sum(
+            math.prod(a.sharding.shard_shape(a.shape)) * a.dtype.itemsize
+            for a in jax.tree.leaves(self.cache.get("attn")))
+        self.cache_alias_bytes: dict[tuple, int | None] = {}
         self.page_table = np.zeros((max_seqs, self.pages_per_seq), np.int32)
         self.step_idx = 0
         self.prefilled_tokens = 0  # uncached tokens actually computed
@@ -339,8 +350,9 @@ class Engine:
         self._emitted: list[tuple[int, int]] = []  # (req_id, token)/step
         self.last_step_stats: dict | None = None
         self.last_step_logits = None  # device [S, V], debug_logits only
-        # (cache, batch) the last unified launch read, debug_logits only:
-        # what a replay of that step on another backend needs
+        # (copy of the cache, batch) the last unified launch read,
+        # debug_logits only: what a replay of that step on another backend
+        # needs (the launch itself consumed the original)
         self.last_launch = None
         self.last_generate: dict = {}  # drive-loop stats (see generate())
         self._compiled: dict[tuple, object] = {}
@@ -375,26 +387,15 @@ class Engine:
                 # into the executor's traced program — the cache key never
                 # varies with them within one engine.
                 self._compiled[key] = self.executor.build_unified(kcfg)
-            elif kind == "prefill":
-                self._compiled[key] = jax.jit(
-                    functools.partial(M.apply_prefill, self.cfg,
-                                      backend=self.backend,
-                                      kernel_cfg=kcfg)
-                )
-            elif kind.startswith("prefill_cached"):
-                self._compiled[key] = jax.jit(
-                    functools.partial(M.apply_prefill_cached, self.cfg,
-                                      backend=self.backend,
-                                      kernel_cfg=kcfg)
-                )
-            elif kind == "decode":
-                self._compiled[key] = jax.jit(
-                    functools.partial(M.apply_decode, self.cfg,
-                                      backend=self.backend,
-                                      kernel_cfg=kcfg)
-                )
             else:
-                raise ValueError(kind)
+                apply = {"prefill": M.apply_prefill,
+                         "prefill_cached": M.apply_prefill_cached,
+                         "decode": M.apply_decode}[kind.split("/")[0]]
+                # padded launches consume their cache like unified ones
+                self._compiled[key] = jax.jit(
+                    functools.partial(apply, self.cfg, backend=self.backend,
+                                      kernel_cfg=kcfg),
+                    donate_argnums=(1,))
         return self._compiled[key]
 
     # ------------------------------------------------------------------
@@ -409,7 +410,8 @@ class Engine:
         executable behind `key`, memoized per executable-cache key.  A
         unified capture reuses its ahead-of-launch compile; the padded
         kinds lower+compile once per CAPTURE (after the launch was
-        recorded, so it never pollutes launch timing).  It lets warm
+        recorded, so it never pollutes launch timing; lowering reads only
+        the shapes of `args`).  It lets warm
         launches stamp device-side cost into the latency grid — the refit
         can then split observed latency into a device-time floor and host
         overhead (`tune.refit_from_telemetry(separate_host_overhead=...)`)."""
@@ -722,6 +724,9 @@ class Engine:
                 sp.args["tokens"] = pack.tokens
             flight.out = self._launch_unified(batch, pack, step)
             flight.pack = pack
+            stats["cache_alias_bytes"] = self.cache_alias_bytes[
+                self._unified_key(pack)]
+            stats["kv_pool_bytes"] = self.kv_pool_bytes
         if dec.prefill_reqs and self.prefix_cache is not None:
             with self._span("prefix_index", step):
                 for r in dec.prefill_reqs:
@@ -1007,24 +1012,32 @@ class Engine:
             pack.sampling = self._sampling_rows(s, fill)
         return batch, pack
 
+    def _unified_key(self, pack: _PackedLaunch) -> tuple:
+        return ("unified", 2 * self.max_seqs, pack.tokens, pack.kcfg)
+
     def _launch_unified(self, batch: dict, pack: _PackedLaunch, step: int):
         """Dispatch one packed launch of step `step`; returns the
         device-side result ([S] sampled ids fused, [S, V] last logits
-        otherwise) WITHOUT transferring it to the host."""
+        otherwise) WITHOUT transferring it to the host.  The launch
+        consumes `self.cache` and leaves the new one there."""
         tel = self.telemetry
         pre_captures = len(self.compile_events)
-        exe_key = ("unified", 2 * self.max_seqs, pack.tokens, pack.kcfg)
-        fn = self._get_fn("unified", 2 * self.max_seqs, pack.tokens,
-                          pack.kcfg)
+        exe_key = self._unified_key(pack)
+        fn = self._get_fn(*exe_key)
         self.device_calls["unified"] += 1
         cache_in = self.cache
         if exe_key not in self.executables:
             # the launch below reuses this compile
             with self._span("compile", step):
                 t_compile = time.perf_counter()
-                self.executables[exe_key] = fn.lower(
-                    self.params, cache_in, batch).compile()
+                exe = fn.lower(self.params, cache_in, batch).compile()
+                self.executables[exe_key] = exe
                 self.compile_seconds += time.perf_counter() - t_compile
+                ma = exe.memory_analysis()
+                self.cache_alias_bytes[exe_key] = (
+                    None if ma is None else ma.alias_size_in_bytes)
+        if self._debug_logits:
+            self.last_launch = (jax.tree.map(jnp.copy, cache_in), batch)
         with self._span("dispatch", step) as launch:
             ret = fn(self.params, cache_in, batch)
         if self._spec:
@@ -1038,8 +1051,7 @@ class Engine:
             out, self.last_step_logits, new_cache = ret
         else:
             out, new_cache = ret
-        if self._debug_logits:
-            self.last_launch = (cache_in, batch)
+        del cache_in  # consumed by the launch
         if tel:
             compiled = len(self.compile_events) > pre_captures
             timed = compiled or tel.time_this_launch()
@@ -1051,7 +1063,7 @@ class Engine:
                 grid_phase="unified", timed=timed,
                 cost=self._launch_costs.get(exe_key))
             if compiled:  # AFTER record_launch: never pollutes timing
-                self._exe_cost(exe_key, fn, self.params, cache_in, batch)
+                self._exe_cost(exe_key, fn)
         self.cache = new_cache
         self.launched_token_slots += pack.tokens
         return out
@@ -1176,6 +1188,7 @@ class Engine:
         self.device_calls[kind] += 1
         with self._span("dispatch", self.step_idx) as launch:
             logits, new_cache = fn(self.params, cache_in, batch)
+        del cache_in  # consumed by the launch
         if tel:
             compiled = len(self.compile_events) > pre_captures
             timed = compiled or tel.time_this_launch()
@@ -1185,8 +1198,8 @@ class Engine:
                 kind, profile, key[3], launch.t0, tel.clock.now(),
                 compiled=compiled, tokens=tokens, grid_phase=grid_phase,
                 timed=timed, cost=self._launch_costs.get(key))
-            if compiled:
-                self._exe_cost(key, fn, self.params, cache_in, batch)
+            if compiled:  # new_cache has the consumed cache's shapes
+                self._exe_cost(key, fn, self.params, new_cache, batch)
         self.launched_token_slots += tokens
         return logits, new_cache
 

@@ -55,7 +55,8 @@ class DeviceMeshExecutor:
       `(params, cache, batch) -> apply_unified outputs`; the engine
       caches one per (token-bucket, kernel-config) key and a steady step
       calls it exactly once — the one-dispatch invariant holds for every
-      executor.
+      executor.  The callable DONATES its cache: a launch consumes the
+      pool it is given and returns the live one, updated in place.
     * Replicated vs sharded is an executor-internal decision; the engine
       never sees specs, only placed pytrees and callables.
     """
@@ -97,7 +98,7 @@ class SingleDeviceExecutor(DeviceMeshExecutor):
             kernel_cfg=kernel_cfg, num_decode_seqs=self.max_seqs,
             sample=self.fused, seed=self.seed,
             return_logits=self.debug_logits, max_draft=self.max_draft,
-        ))
+        ), donate_argnums=(1,))
 
 
 class TensorParallelExecutor(DeviceMeshExecutor):
@@ -162,7 +163,7 @@ class TensorParallelExecutor(DeviceMeshExecutor):
                 out_specs=out_specs, check_vma=False,
             )(params, cache, batch)
 
-        return jax.jit(run)
+        return jax.jit(run, donate_argnums=(1,))
 
 
 class PipelineParallelExecutor(DeviceMeshExecutor):

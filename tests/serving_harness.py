@@ -124,6 +124,31 @@ def assert_step_invariants(eng: Engine, stats: dict) -> None:
         assert (dev["free_pages"] + dev["referenced_pages"]
                 + dev["evictable_pages"] == eng.alloc.num_pages - 1), dev
         assert dev["total_refs"] == refs, dev
+    # a packed launch donates the cache: its executable aliases the whole
+    # KV pool (per device) to its output, so the pool is updated in place
+    if "cache_alias_bytes" in stats:
+        assert stats["kv_pool_bytes"] == eng.kv_pool_bytes > 0, stats
+        assert stats["cache_alias_bytes"] >= eng.kv_pool_bytes, stats
+
+
+def model_launches(eng: Engine) -> int:
+    return sum(n for k, n in eng.device_calls.items() if k != "sample")
+
+
+def pool_leaves(eng: Engine) -> list:
+    """The arrays of the engine's KV pool (none for a pure SSM model)."""
+    return jax.tree.leaves(eng.cache.get("attn"))
+
+
+def assert_cache_consumed(eng: Engine, before: list, launches: int) -> None:
+    """A step that launched the model consumed the KV pool it was given
+    (`before`, from `pool_leaves`): every array of it is deleted, and
+    `eng.cache` holds live arrays.  Slot-indexed SSM state is not a pool:
+    a padded prefill starts it from zeros and merges rows back."""
+    if model_launches(eng) == launches:
+        return
+    assert all(a.is_deleted() for a in before), "launch input pool alive"
+    assert not any(a.is_deleted() for a in jax.tree.leaves(eng.cache))
 
 
 def run_requests(eng: Engine, prompts, *, max_new_tokens: int = 8,
@@ -137,10 +162,12 @@ def run_requests(eng: Engine, prompts, *, max_new_tokens: int = 8,
         eng.add_request(r)
     stats: list[dict] = []
     while eng.sched.has_work and len(stats) < max_steps:
+        before, launches = pool_leaves(eng), model_launches(eng)
         st = eng.step()
         stats.append(st)
         if check_invariants:
             assert_step_invariants(eng, st)
+            assert_cache_consumed(eng, before, launches)
     assert not eng.sched.has_work, \
         f"engine did not drain within {max_steps} steps"
     if expect_finished:

@@ -27,9 +27,10 @@ import numpy as np
 import pytest
 
 from repro.models import sampling
-from serving_harness import (assert_same_outputs, assert_step_invariants,
-                             build_cfg_params, build_engine, greedy_reference,
-                             make_prompts, run_requests)
+from serving_harness import (assert_cache_consumed, assert_same_outputs,
+                             assert_step_invariants, build_cfg_params,
+                             build_engine, greedy_reference, make_prompts,
+                             model_launches, pool_leaves, run_requests)
 from repro.serving.request import Request, State
 
 MAX_NEW = 6
@@ -49,13 +50,18 @@ def prompts(cfg_params):
 
 def stream_requests(eng, reqs, **kw):
     """Drive `stream()` to drain; returns the yielded (req_id, token)
-    pairs grouped per request, checking step invariants as it goes."""
+    pairs grouped per request, checking step invariants as it goes —
+    among them that each launch consumed the cache before it (the
+    double-buffered loop hands step N's output cache to step N+1)."""
     for r in reqs:
         eng.submit(r)
     by_req: dict[int, list[int]] = {}
+    before, launches = pool_leaves(eng), model_launches(eng)
     for rid, tok in eng.stream(**kw):
         by_req.setdefault(rid, []).append(tok)
         assert_step_invariants(eng, eng.last_step_stats)
+        assert_cache_consumed(eng, before, launches)
+        before, launches = pool_leaves(eng), model_launches(eng)
     return by_req
 
 

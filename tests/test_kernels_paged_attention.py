@@ -1,5 +1,8 @@
 """Paged-attention kernels vs the pure-jnp oracle: shape/dtype sweeps,
-ragged contexts, GQA ratios, non-power-of-two pages, static-grid masking."""
+ragged contexts, GQA ratios, non-power-of-two pages, static-grid masking,
+and the in-place pool write.  Every kernel reads the whole stacked pool
+[L, Hkv, P, ps, 2D] at a layer index; the cases fill every layer with
+different values, so reading the wrong layer fails."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -14,16 +17,23 @@ except ImportError:  # collect-and-skip fallback (requirements-dev.txt)
 from repro.kernels.paged_attention import ops, ref
 
 
+LAYERS = 2  # layer indices 0 and L - 1 are both read
+
+
+def make_pool(rng, hkv, p, ps, d, dtype=jnp.float32):
+    return jnp.asarray(rng.standard_normal((LAYERS, hkv, p, ps, 2 * d)),
+                       dtype)
+
+
 def make_case(rng, s, hq, hkv, d, ps, np_, ctx, dtype=jnp.float32):
     p = s * np_ + 1
     q = jnp.asarray(rng.standard_normal((s, hq, d)), dtype)
-    kp = jnp.asarray(rng.standard_normal((hkv, p, ps, d)), dtype)
-    vp = jnp.asarray(rng.standard_normal((hkv, p, ps, d)), dtype)
+    kv = make_pool(rng, hkv, p, ps, d, dtype)
     pt = jnp.asarray(
         rng.permutation(p - 1)[: s * np_].reshape(s, np_) + 1, jnp.int32
     )
     ctx = jnp.asarray(ctx, jnp.int32)
-    return q, kp, vp, pt, ctx
+    return q, kv, pt, ctx
 
 
 DECODE_CASES = [
@@ -37,14 +47,16 @@ DECODE_CASES = [
 ]
 
 
+@pytest.mark.parametrize("layer", [0, LAYERS - 1])
 @pytest.mark.parametrize("variant", ["baseline", "gqa", "segmented"])
 @pytest.mark.parametrize("case", DECODE_CASES)
-def test_decode_matches_oracle(variant, case):
+def test_decode_matches_oracle(variant, case, layer):
     s, hq, hkv, d, ps, np_, ctx, dtype, tol = case
     rng = np.random.default_rng(hash((variant, s, hq, d)) % 2**31)
-    q, kp, vp, pt, ctxa = make_case(rng, s, hq, hkv, d, ps, np_, ctx, dtype)
-    expected = ref.paged_attention_decode_ref(q, kp, vp, pt, ctxa)
-    got = ops.paged_attention_decode(q, kp, vp, pt, ctxa, variant=variant)
+    q, kv, pt, ctxa = make_case(rng, s, hq, hkv, d, ps, np_, ctx, dtype)
+    expected = ref.paged_attention_decode_ref(q, kv, layer, pt, ctxa)
+    got = ops.paged_attention_decode(q, kv, layer, pt, ctxa,
+                                     variant=variant)
     np.testing.assert_allclose(
         np.asarray(got, np.float32), np.asarray(expected, np.float32),
         atol=tol, rtol=tol,
@@ -57,10 +69,10 @@ def test_decode_adjustable_tiles_and_segments(tile, nseg):
     """C4: tile decoupled from page size; C3: any segment count."""
     rng = np.random.default_rng(7)
     s, hq, hkv, d, ps, np_ = 3, 8, 2, 128, 16, 8
-    q, kp, vp, pt, ctx = make_case(rng, s, hq, hkv, d, ps, np_, [128, 3, 51])
-    expected = ref.paged_attention_decode_ref(q, kp, vp, pt, ctx)
+    q, kv, pt, ctx = make_case(rng, s, hq, hkv, d, ps, np_, [128, 3, 51])
+    expected = ref.paged_attention_decode_ref(q, kv, 1, pt, ctx)
     got = ops.paged_attention_decode(
-        q, kp, vp, pt, ctx, variant="segmented", tile=tile, num_segments=nseg
+        q, kv, 1, pt, ctx, variant="segmented", tile=tile, num_segments=nseg
     )
     np.testing.assert_allclose(
         np.asarray(got), np.asarray(expected), atol=2e-5, rtol=2e-5
@@ -70,10 +82,10 @@ def test_decode_adjustable_tiles_and_segments(tile, nseg):
 def test_decode_static_grid_dead_seqs_zero():
     """C5: padded (dead) sequences must produce exact zeros."""
     rng = np.random.default_rng(8)
-    q, kp, vp, pt, ctx = make_case(rng, 4, 8, 2, 128, 16, 4, [10, 0, 0, 7])
+    q, kv, pt, ctx = make_case(rng, 4, 8, 2, 128, 16, 4, [10, 0, 0, 7])
     for variant in ("baseline", "gqa", "segmented"):
         got = np.asarray(
-            ops.paged_attention_decode(q, kp, vp, pt, ctx, variant=variant)
+            ops.paged_attention_decode(q, kv, 0, pt, ctx, variant=variant)
         )
         assert (got[1] == 0).all() and (got[2] == 0).all(), variant
         assert np.isfinite(got).all(), variant
@@ -95,9 +107,10 @@ def test_decode_property_random_ragged(s, hkv, group, np_, data):
         st.lists(st.integers(0, np_ * ps), min_size=s, max_size=s)
     )
     rng = np.random.default_rng(data.draw(st.integers(0, 2**30)))
-    q, kp, vp, pt, ctxa = make_case(rng, s, hkv * group, hkv, d, ps, np_, ctx)
-    expected = ref.paged_attention_decode_ref(q, kp, vp, pt, ctxa)
-    got = ops.paged_attention_decode(q, kp, vp, pt, ctxa, variant="gqa")
+    layer = data.draw(st.integers(0, LAYERS - 1))
+    q, kv, pt, ctxa = make_case(rng, s, hkv * group, hkv, d, ps, np_, ctx)
+    expected = ref.paged_attention_decode_ref(q, kv, layer, pt, ctxa)
+    got = ops.paged_attention_decode(q, kv, layer, pt, ctxa, variant="gqa")
     np.testing.assert_allclose(
         np.asarray(got), np.asarray(expected), atol=3e-5, rtol=3e-5
     )
@@ -116,12 +129,11 @@ def make_prefill_case(rng, qlens, ctx_prior, hq, hkv, d, ps, np_, t_pad,
     ctx = jnp.asarray(ctx_prior, jnp.int32) + qlens
     qsl = jnp.concatenate([jnp.zeros(1, jnp.int32), jnp.cumsum(qlens)])
     q = jnp.asarray(rng.standard_normal((t_pad, hq, d)), dtype)
-    kp = jnp.asarray(rng.standard_normal((hkv, p, ps, d)), dtype)
-    vp = jnp.asarray(rng.standard_normal((hkv, p, ps, d)), dtype)
+    kv = make_pool(rng, hkv, p, ps, d, dtype)
     pt = jnp.asarray(
         rng.permutation(p - 1)[: s * np_].reshape(s, np_) + 1, jnp.int32
     )
-    return q, kp, vp, pt, ctx, qsl, qlens
+    return q, kv, pt, ctx, qsl, qlens
 
 
 PREFILL_CASES = [
@@ -134,16 +146,18 @@ PREFILL_CASES = [
 ]
 
 
+@pytest.mark.parametrize("layer", [0, LAYERS - 1])
 @pytest.mark.parametrize("case", PREFILL_CASES)
-def test_prefill_matches_oracle(case):
+def test_prefill_matches_oracle(case, layer):
     qlens, ctxp, hq, hkv, d, ps, np_, t_pad, bq = case
     rng = np.random.default_rng(hash(tuple(qlens)) % 2**31)
-    q, kp, vp, pt, ctx, qsl, ql = make_prefill_case(
+    q, kv, pt, ctx, qsl, ql = make_prefill_case(
         rng, qlens, ctxp, hq, hkv, d, ps, np_, t_pad
     )
-    expected = ref.paged_attention_prefill_ref(q, kp, vp, pt, ctx, qsl, ql)
+    expected = ref.paged_attention_prefill_ref(q, kv, layer, pt, ctx, qsl,
+                                               ql)
     got = ops.paged_attention_prefill(
-        q, kp, vp, pt, ctx, qsl, ql, block_q=bq
+        q, kv, layer, pt, ctx, qsl, ql, block_q=bq
     )
     np.testing.assert_allclose(
         np.asarray(got), np.asarray(expected), atol=3e-5, rtol=3e-5
@@ -152,11 +166,11 @@ def test_prefill_matches_oracle(case):
 
 def test_prefill_dead_rows_zero():
     rng = np.random.default_rng(9)
-    q, kp, vp, pt, ctx, qsl, ql = make_prefill_case(
+    q, kv, pt, ctx, qsl, ql = make_prefill_case(
         rng, [10, 5], [0, 0], 4, 2, 64, 16, 4, 32, jnp.float32
     )
     got = np.asarray(
-        ops.paged_attention_prefill(q, kp, vp, pt, ctx, qsl, ql, block_q=8)
+        ops.paged_attention_prefill(q, kv, 1, pt, ctx, qsl, ql, block_q=8)
     )
     assert (got[15:] == 0).all()
     assert np.isfinite(got).all()
@@ -198,3 +212,48 @@ def test_segment_merge_associativity():
     np.testing.assert_allclose(
         np.asarray(merged), np.asarray(full), atol=1e-5, rtol=1e-5
     )
+
+
+# ---------------------------------------------------------------------------
+# in-place pool write
+# ---------------------------------------------------------------------------
+
+
+def _write_case(ps, p):
+    """Slots as a packed step lays them out: two decode rows, a chunk
+    that starts mid-block and crosses a page, padding (trash slots)
+    between runs, and a chunk that fills one block exactly.  Pages 1-2
+    stand for pages a prefix cache shares: no slot points into them."""
+    trash = p * ps
+    runs = [
+        [5 * ps + 3],  # decode row
+        [9 * ps + ps - 1],  # decode row at a page's last slot
+        list(range(6 * ps + 5, 6 * ps + ps)) + list(range(7 * ps,
+                                                          7 * ps + 9)),
+        [trash] * 3,  # padded rows
+        list(range(8 * ps, 8 * ps + 16)),
+    ]
+    return np.asarray([x for r in runs for x in r], np.int32)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("d", [64, 128])
+@pytest.mark.parametrize("layer", [0, LAYERS - 1])
+def test_write_rows_matches_oracle(layer, d, dtype):
+    """`write_rows` (Pallas, in place) == the `.at[].set` oracle: rows at
+    their slots, trash slots dropped, every other row untouched (shared
+    prefix pages, other layers)."""
+    rng = np.random.default_rng(11)
+    hkv, ps, p = 2, 32, 12
+    pool = make_pool(rng, hkv, p, ps, d, dtype)
+    slots = _write_case(ps, p)
+    rows = jnp.asarray(rng.standard_normal((len(slots), hkv, 2 * d)), dtype)
+    want = ref.write_rows_ref(pool, layer, rows, jnp.asarray(slots))
+    plan = ops.write_plan(jnp.asarray(slots), ps, p)
+    assert int(plan["num_blocks"]) == 6
+    got = ops.write_rows(pool, layer, rows, plan)
+    np.testing.assert_array_equal(np.asarray(got, np.float32),
+                                  np.asarray(want, np.float32))
+    before, after = np.asarray(pool), np.asarray(got)
+    np.testing.assert_array_equal(after[1 - layer], before[1 - layer])
+    np.testing.assert_array_equal(after[:, :, 1:3], before[:, :, 1:3])

@@ -117,22 +117,22 @@ from repro.distributed.sharding import make_mesh
 
 mesh = make_mesh((4,), ("tp",))
 specs = KV.make_kv_cache_specs(2, 8, 1, 6, 4, 16, 16, "float32")
+assert list(specs) == ["kv_pages"], list(specs)  # one pool, K|V rows
 local = KV.shard_cache_specs(specs, 4)
-assert local["k_pages"].shape == (2, 2, 1, 6, 4, 16), local["k_pages"].shape
+assert local["kv_pages"].shape == (2, 2, 1, 6, 4, 32), local["kv_pages"].shape
 
 sh = PS.assign_cache_shardings({"attn": specs}, mesh=mesh, batch_axes=(),
                                model_axis="tp")["attn"]
-for name in ("k_pages", "v_pages"):
-    # head axis (dim 1) on "tp", everything else replicated
-    spec = sh[name].spec
-    assert spec[1] == "tp", (name, spec)
-    assert all(s is None for i, s in enumerate(spec) if i != 1), (name, spec)
+# head axis (dim 1) on "tp", everything else replicated
+spec = sh["kv_pages"].spec
+assert spec[1] == "tp", spec
+assert all(s is None for i, s in enumerate(spec) if i != 1), spec
 
 # round-trip: place a counting array, check each device holds its
 # CONTIGUOUS head block in mesh order, and reassembly is exact
-shape = specs["k_pages"].shape
+shape = specs["kv_pages"].shape
 arr = jnp.arange(np.prod(shape), dtype=jnp.float32).reshape(shape)
-placed = jax.device_put(arr, sh["k_pages"])
+placed = jax.device_put(arr, sh["kv_pages"])
 starts = {}
 for s in placed.addressable_shards:
     sl = s.index[1]

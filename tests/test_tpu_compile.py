@@ -14,6 +14,8 @@ every test file.  Keep these compiles in this one file.
 from __future__ import annotations
 
 import functools
+import math
+import re
 
 import jax
 import jax.numpy as jnp
@@ -58,19 +60,21 @@ def _spec(sharding, shape, dtype):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
 
-# (Hq, Hkv, D): Qwen2.5-3B, and SmolLM's head_dim 64, which the ops pad
-# to 128 lanes
+# (Hq, Hkv, D): Qwen2.5-3B, and SmolLM's head_dim 64, whose K|V pool row
+# is 128 lanes
 QWEN = (16, 2, 128)
 SMOLLM = (9, 3, 64)
 MAX_SEQS, PAGES, PAGE, PAGES_PER_SEQ, CHUNK_TOKENS = 8, 2048, 16, 128, 512
+LAYERS = 36  # the kernels read the whole stacked pool at a layer index
 
 
 def _kernel_args(sh, heads, dtype, *, seqs, tokens):
     hq, hkv, d = heads
     i32 = jnp.int32
-    pages = _spec(sh, (hkv, PAGES, PAGE, d), dtype)
     return dict(
-        q=_spec(sh, (tokens, hq, d), dtype), k=pages, v=pages,
+        q=_spec(sh, (tokens, hq, d), dtype),
+        kv=_spec(sh, (LAYERS, hkv, PAGES, PAGE, 2 * d), dtype),
+        layer=_spec(sh, (), i32),
         pt=_spec(sh, (seqs, PAGES_PER_SEQ), i32),
         ctx=_spec(sh, (seqs,), i32),
         qsl=_spec(sh, (seqs + 1,), i32),
@@ -78,26 +82,28 @@ def _kernel_args(sh, heads, dtype, *, seqs, tokens):
 
 
 def _decode(variant, a):
-    return jax.jit(lambda q, k, v, pt, ctx: ops.paged_attention_decode(
-        q, k, v, pt, ctx, variant=variant, num_segments=8, interpret=False,
-    )).lower(a["q"], a["k"], a["v"], a["pt"], a["ctx"])
+    return jax.jit(lambda q, kv, layer, pt, ctx: ops.paged_attention_decode(
+        q, kv, layer, pt, ctx, variant=variant, num_segments=8,
+        interpret=False,
+    )).lower(*(a[n] for n in ("q", "kv", "layer", "pt", "ctx")))
 
 
 def _prefill(a):
-    return jax.jit(lambda q, k, v, pt, ctx, qsl, ql:
+    return jax.jit(lambda q, kv, layer, pt, ctx, qsl, ql:
                    ops.paged_attention_prefill(
-                       q, k, v, pt, ctx, qsl, ql, block_q=16,
+                       q, kv, layer, pt, ctx, qsl, ql, block_q=16,
                        interpret=False,
-                   )).lower(*(a[n] for n in ("q", "k", "v", "pt", "ctx",
+                   )).lower(*(a[n] for n in ("q", "kv", "layer", "pt", "ctx",
                                              "qsl", "qlens")))
 
 
 def _unified(variant, a):
-    return jax.jit(lambda q, k, v, pt, ctx, qsl, ql:
+    return jax.jit(lambda q, kv, layer, pt, ctx, qsl, ql:
                    ops.paged_attention_unified(
-                       q, k, v, pt, ctx, qsl, ql, num_decode_seqs=MAX_SEQS,
-                       variant=variant, num_segments=16, interpret=False,
-                   )).lower(*(a[n] for n in ("q", "k", "v", "pt", "ctx",
+                       q, kv, layer, pt, ctx, qsl, ql,
+                       num_decode_seqs=MAX_SEQS, variant=variant,
+                       num_segments=16, interpret=False,
+                   )).lower(*(a[n] for n in ("q", "kv", "layer", "pt", "ctx",
                                              "qsl", "qlens")))
 
 
@@ -168,3 +174,91 @@ def test_qwen_unified_step_compiles_for_v5e(one_chip, monkeypatch):
     total = (ma.argument_size_in_bytes + ma.output_size_in_bytes
              + ma.temp_size_in_bytes - ma.alias_size_in_bytes)
     assert total < HBM_BYTES, ma
+
+
+# Each benchmark cell's geometry (bench/configs/<arch>.json "engine"): the
+# KV slots, pages of 128 tokens, the longest context, a 512-token chunk,
+# and the kernel configuration its packed step dispatches most.
+CELLS = {
+    "qwen2.5-3b": (32, 770, 8192, KernelConfig("segmented", num_segments=16)),
+    "smollm-135m": (128, 2056, 2048, KernelConfig("gqa")),
+}
+# Instructions whose result may be pool-sized: the arguments, tuple
+# plumbing, the layer loop, free reshapes, and the Pallas kernels.
+POOL_SIZED_OK = {"parameter", "tuple", "get-tuple-element", "while",
+                 "bitcast"}
+_HLO_RESULT = re.compile(
+    r"^\s*(?:ROOT\s+)?%\S+\s*=\s*(\w+)\[([\d,]*)\]\S*\s+([\w-]+)\(")
+_BYTES = {"pred": 1, "s8": 1, "u8": 1, "bf16": 2, "f16": 2, "s16": 2,
+          "u16": 2, "f32": 4, "s32": 4, "u32": 4, "f64": 8, "s64": 8,
+          "u64": 8}
+
+
+def _pool_sized(hlo_text: str, limit: int) -> list[str]:
+    """HLO lines outside POOL_SIZED_OK whose array result holds at least
+    `limit` bytes, in the computations that own buffers: the entry and the
+    loop bodies, not the bodies of fusions (a fusion's inner values live in
+    registers and VMEM, its result is the buffer)."""
+    fused = set(re.findall(r"\bfusion\(.*?calls=%([\w.-]+)", hlo_text))
+    out, comp = [], None
+    for line in hlo_text.splitlines():
+        head = re.match(r"^(?:ENTRY\s+)?%([\w.-]+)\s.*\{\s*$", line)
+        if head:
+            comp = head.group(1)
+            continue
+        m = _HLO_RESULT.match(line)
+        if not m or comp in fused:
+            continue  # tuple-shaped results, non-instruction lines
+        dtype, dims, opcode = m.groups()
+        size = _BYTES.get(dtype, 4) * math.prod(
+            int(d) for d in dims.split(",") if d)
+        if size < limit or opcode in POOL_SIZED_OK:
+            continue
+        if opcode == "custom-call" and "tpu_custom_call" in line:
+            continue
+        out.append(line.strip()[:200])
+    return out
+
+
+@pytest.mark.parametrize("arch", sorted(CELLS))
+def test_unified_step_updates_pool_in_place(one_chip, monkeypatch, arch):
+    """The packed step at a benchmark cell's geometry, cache donated as
+    the engine's executor donates it.  No instruction but the kernels and
+    the loop plumbing yields a result as large as one layer's K pool (no
+    slice, pad, relayout or rewrite of a layer), the compile aliases the
+    whole pool to the output, and it needs less scratch than one layer's
+    K pool.  The fused sampler is left out: it never touches the pool, and
+    its [S, V] sort and noise buffers (124 MB for Qwen, 203 MB for SmolLM)
+    would swamp the scratch bound; `test_qwen_unified_step_compiles_for_v5e`
+    compiles the step with it."""
+    monkeypatch.setattr(repro.kernels, "interpret_mode",
+                        lambda interpret=None: False)
+    max_seqs, pages, max_len, kcfg = CELLS[arch]
+    cfg = ARCHS[arch].replace(page_size=128)
+    sh = one_chip
+    place = lambda t: jax.tree.map(  # noqa: E731
+        lambda x: _spec(sh, x.shape, x.dtype), t)
+    params = place(M.init_abstract(cfg))
+    cache = place(M.make_cache_specs(cfg, max_seqs=max_seqs,
+                                     num_pages=pages))
+    t, s = max_seqs + CHUNK_TOKENS, 2 * max_seqs
+    batch = {
+        "inputs": (1, t), "positions": (1, t), "slot_mapping": (1, t),
+        "token_source": (1, t), "page_table": (s, max_len // 128),
+        "context_lens": (s,), "query_lens": (s,), "query_start_loc": (s + 1,),
+        "prev_tokens": (s,),
+    }
+    batch = {k: _spec(sh, v, jnp.int32) for k, v in batch.items()}
+    step = jax.jit(functools.partial(
+        M.apply_unified, cfg, backend="pallas", kernel_cfg=kcfg,
+        num_decode_seqs=max_seqs), donate_argnums=(1,))
+    compiled = step.lower(params, cache, batch).compile()
+    layer_k_bytes = (cfg.num_kv_heads * pages * 128 * cfg.resolved_head_dim
+                     * jnp.dtype(cfg.param_dtype).itemsize)
+    big = _pool_sized(compiled.as_text(), layer_k_bytes)
+    assert not big, "\n".join(big)
+    pool_bytes = sum(x.size * x.dtype.itemsize
+                     for x in jax.tree.leaves(cache["attn"]))
+    ma = compiled.memory_analysis()
+    assert ma.alias_size_in_bytes >= pool_bytes, ma
+    assert ma.temp_size_in_bytes < layer_k_bytes, ma

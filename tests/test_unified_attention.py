@@ -50,11 +50,12 @@ def make_packed_case(rng, dec_ctx, qlens_pref, ctx_prior, *, hq=4, hkv=2,
                    np.int32)
     qsl = np.concatenate([[0], np.cumsum(qlens)]).astype(np.int32)
     q = jnp.asarray(rng.standard_normal((t, hq, d)), jnp.float32)
-    kp = jnp.asarray(rng.standard_normal((hkv, p, ps, d)), jnp.float32)
-    vp = jnp.asarray(rng.standard_normal((hkv, p, ps, d)), jnp.float32)
+    # the stacked pool of two layers; the op tests read layer 1
+    kv = jnp.asarray(rng.standard_normal((2, hkv, p, ps, 2 * d)),
+                     jnp.float32)
     pt = jnp.asarray(
         rng.permutation(p - 1)[: s * np_].reshape(s, np_) + 1, jnp.int32)
-    return (q, kp, vp, pt, jnp.asarray(ctx), jnp.asarray(qsl),
+    return (q, kv, pt, jnp.asarray(ctx), jnp.asarray(qsl),
             jnp.asarray(qlens), nd)
 
 
@@ -63,14 +64,14 @@ def test_unified_op_bit_identical_to_separate_launches():
     kernel — the packed launch must reproduce the separate launches it
     replaces BIT-identically (same kernels, same inputs)."""
     rng = np.random.default_rng(0)
-    q, kp, vp, pt, ctx, qsl, ql, nd = make_packed_case(
+    q, kv, pt, ctx, qsl, ql, nd = make_packed_case(
         rng, dec_ctx=[37, 0, 52], qlens_pref=[9, 17], ctx_prior=[0, 23])
     uni = ops.paged_attention_unified(
-        q, kp, vp, pt, ctx, qsl, ql, num_decode_seqs=nd, block_q=8)
+        q, kv, 1, pt, ctx, qsl, ql, num_decode_seqs=nd, block_q=8)
     dec = ops.paged_attention_decode(
-        q[:nd], kp, vp, pt[:nd], ctx[:nd], variant="gqa")
+        q[:nd], kv, 1, pt[:nd], ctx[:nd], variant="gqa")
     pre = ops.paged_attention_prefill(
-        q[nd:], kp, vp, pt[nd:], ctx[nd:], qsl[nd:] - nd, ql[nd:],
+        q[nd:], kv, 1, pt[nd:], ctx[nd:], qsl[nd:] - nd, ql[nd:],
         block_q=8)
     np.testing.assert_array_equal(np.asarray(uni[:nd]), np.asarray(dec))
     np.testing.assert_array_equal(np.asarray(uni[nd:]), np.asarray(pre))
@@ -80,13 +81,13 @@ def test_unified_op_matches_ragged_oracle():
     """Against the pure-jnp ragged oracle, which treats a decode row as a
     1-token segment — the generalization the unified layout leans on."""
     rng = np.random.default_rng(1)
-    q, kp, vp, pt, ctx, qsl, ql, nd = make_packed_case(
+    q, kv, pt, ctx, qsl, ql, nd = make_packed_case(
         rng, dec_ctx=[21, 64, 0, 5], qlens_pref=[13, 32, 1],
         ctx_prior=[0, 16, 30])
-    expected = ref.paged_attention_prefill_ref(q, kp, vp, pt, ctx, qsl, ql)
+    expected = ref.paged_attention_prefill_ref(q, kv, 1, pt, ctx, qsl, ql)
     for variant in ("gqa", "segmented"):
         got = ops.paged_attention_unified(
-            q, kp, vp, pt, ctx, qsl, ql, num_decode_seqs=nd,
+            q, kv, 1, pt, ctx, qsl, ql, num_decode_seqs=nd,
             variant=variant, block_q=8)
         np.testing.assert_allclose(
             np.asarray(got), np.asarray(expected), atol=3e-5, rtol=3e-5)
@@ -98,21 +99,21 @@ def test_ragged_xla_backend_matches_pallas():
     backends must agree on the same packed metadata — including q == 1
     rows, which only the unified entry routes to the decode kernel."""
     rng = np.random.default_rng(2)
-    q, kp, vp, pt, ctx, qsl, ql, nd = make_packed_case(
+    q, kv, pt, ctx, qsl, ql, nd = make_packed_case(
         rng, dec_ctx=[18, 0], qlens_pref=[7, 24], ctx_prior=[9, 0])
-    kp4, vp4 = kp[:, None], vp[:, None]  # add the (single) pool axis
+    kv6 = kv[:, :, None]  # add the (single) pool axis
     out_xla = attn_backend.prefill_attention_ragged(
-        "xla", q, kp4, vp4, pt, ctx, qsl, ql)
+        "xla", q, kv6, 1, pt, ctx, qsl, ql)
     out_pal = attn_backend.prefill_attention_ragged(
-        "pallas", q, kp4, vp4, pt, ctx, qsl, ql,
+        "pallas", q, kv6, 1, pt, ctx, qsl, ql,
         kernel_cfg=heuristics.KernelConfig("gqa", block_q=8))
     np.testing.assert_allclose(
         np.asarray(out_xla), np.asarray(out_pal), atol=3e-5, rtol=3e-5)
     # unified entry point: same agreement with the decode split active
     uni_xla = attn_backend.unified_attention(
-        "xla", q, kp4, vp4, pt, ctx, qsl, ql, num_decode_seqs=nd)
+        "xla", q, kv6, 1, pt, ctx, qsl, ql, num_decode_seqs=nd)
     uni_pal = attn_backend.unified_attention(
-        "pallas", q, kp4, vp4, pt, ctx, qsl, ql, num_decode_seqs=nd,
+        "pallas", q, kv6, 1, pt, ctx, qsl, ql, num_decode_seqs=nd,
         kernel_cfg=heuristics.KernelConfig("gqa", block_q=8))
     np.testing.assert_allclose(
         np.asarray(uni_xla), np.asarray(uni_pal), atol=3e-5, rtol=3e-5)
@@ -120,13 +121,13 @@ def test_ragged_xla_backend_matches_pallas():
 
 def test_ragged_multi_pool_is_a_hard_error():
     rng = np.random.default_rng(3)
-    q, kp, vp, pt, ctx, qsl, ql, nd = make_packed_case(
+    q, kv, pt, ctx, qsl, ql, nd = make_packed_case(
         rng, dec_ctx=[8], qlens_pref=[4], ctx_prior=[0])
-    two_pools = jnp.stack([kp, kp], axis=1)
+    two_pools = jnp.stack([kv, kv], axis=2)
     for backend in ("xla", "pallas"):
         with pytest.raises(KV.ShardingError, match="num_pools=2"):
             attn_backend.prefill_attention_ragged(
-                backend, q, two_pools, two_pools, pt, ctx, qsl, ql)
+                backend, q, two_pools, 0, pt, ctx, qsl, ql)
 
 
 # ---------------------------------------------------------------------------
@@ -237,6 +238,37 @@ def test_packed_equivalence_pallas_backend(smollm):
     H.assert_same_outputs(padded, packed, label_a="padded",
                           label_b="packed")
     assert packed.total("partial_prefills") > 0
+
+
+def test_debug_launch_copy_replays_on_xla(smollm):
+    """Under `debug_logits` the engine keeps a copy of a unified launch's
+    cache, since the launch consumed (deleted) the original; replaying
+    that copy on the xla executor gives the pallas step's logits on a
+    step that packs decode and chunk rows."""
+    from repro.serving.executor import SingleDeviceExecutor
+    from repro.serving.request import Request
+    cfg, params = smollm
+    eng = H.build_engine(cfg, params, max_seqs=2, max_model_len=128,
+                         backend="pallas", debug_logits=True,
+                         enable_chunked_prefill=True,
+                         max_prefill_tokens=BUDGET)
+    rng = np.random.default_rng(6)
+    for p in H.make_prompts(cfg, rng, (5, 2 * BUDGET + 3)):
+        eng.add_request(Request(prompt=p, max_new_tokens=4))
+    while True:
+        assert eng.sched.has_work, "no step mixed decode and chunk rows"
+        before = H.pool_leaves(eng)
+        stats = eng.step()
+        if stats["decode"] and stats["prefill"]:
+            break
+    assert all(a.is_deleted() for a in before)
+    cache_in, batch = eng.last_launch
+    xla = SingleDeviceExecutor(
+        cfg, backend="xla", max_seqs=eng.max_seqs, fused=True,
+        seed=eng.seed, debug_logits=True).build_unified(None)
+    _, want, _ = xla(eng.params, cache_in, batch)
+    np.testing.assert_allclose(np.asarray(eng.last_step_logits),
+                               np.asarray(want), atol=1e-4, rtol=1e-4)
 
 
 def test_packed_reduces_compile_events_and_padding(smollm):
