@@ -17,7 +17,8 @@ The contract:
      highest logits; `k <= 0` disables), then top-p (the smallest
      descending-probability prefix whose mass reaches p; `p >= 1`
      disables), then draw from the renormalized survivors.  Boundary ties
-     are all kept (both filters threshold on the logit value).
+     are all kept (both filters threshold on the logit value), and the
+     top-1 always survives.
   3. **Randomness is a pure function of
      (engine seed, request stream id, tokens generated so far)**:
      `key = fold_in(fold_in(key(seed), stream), n_generated)`.  There is
@@ -25,11 +26,35 @@ The contract:
      batch composition, slot or row placement, dead decode rows, or which
      engine path (packed / padded / solo) executed it — the RNG
      reproducibility guarantee the sampling-equivalence suite pins.
+  4. **Thresholds by bisection, never a sort.**  Each filter keeps
+     `x >= cut` for one cut per row.  The cut is found by bisection over
+     the order-preserving int32 image of the float32 values
+     (`_order_keys`): 32 passes, each a compare and a row reduction — a
+     count for top-k (the least key c with `count(x > c) < k`), the row's
+     float32 softmax mass for top-p (the least c with `mass(x > c) < p`).
+     The cut is a value of the row, the one the descending-sort
+     definition picks: exactly for top-k, and for top-p up to the float32
+     rounding of the mass, as the sort's cumulative sum was.  There is no
+     cap on candidates, so a nucleus as wide as the vocabulary is found
+     whole.
+  5. **Only the work the rows ask for** (`filter_rows`).  The top-k search
+     runs only if some sampled row has `top_k > 0`, the top-p search only
+     if some sampled row has `top_p < 1`, and the categorical draw only if
+     some row samples (`lax.cond` on the batch's own parameters); an
+     all-greedy batch is one argmax.  Greedy rows never trip a branch:
+     their filter parameters are neutralised first.  The engine counts
+     the same predicates on the host (packed `step()["sampler"]`).
 """
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+from jax import lax
+
+# An int32 key interval (lo, hi] of up to 2**32 keys halves to one key in
+# 32 steps.
+_BISECT_STEPS = 32
+_NO_CUT = jnp.iinfo(jnp.int32).min  # every key is >= it
 
 
 def request_keys(seed: int, stream_ids, num_generated):
@@ -43,6 +68,14 @@ def request_keys(seed: int, stream_ids, num_generated):
     return jax.vmap(derive)(stream_ids, num_generated)
 
 
+def filter_rows(temperature, top_p, top_k):
+    """Boolean masks `(sampled, top_p rows, top_k rows)`: which rows draw,
+    and which of those need each search.  Plain operators, so the sampler's
+    branches (jax arrays) and the engine's counters (numpy) share them."""
+    sampled = temperature > 0
+    return sampled, sampled & (top_p < 1.0), sampled & (top_k > 0)
+
+
 def scaled_logits(logits, temperature):
     """Temperature scaling with the greedy divisor clamped to 1.0:
     `temperature <= 0` rows pass through UNCHANGED (their argmax is taken
@@ -51,44 +84,104 @@ def scaled_logits(logits, temperature):
     return logits.astype(jnp.float32) / safe_t[:, None]
 
 
-def apply_top_k(logits, top_k):
-    """Keep each row's `top_k` highest logits (ties at the k-th value are
-    all kept); `top_k <= 0` disables the filter for that row."""
-    v = logits.shape[-1]
-    sorted_desc = jnp.sort(logits, axis=-1)[:, ::-1]
-    kth = jnp.take_along_axis(
-        sorted_desc, jnp.clip(top_k[:, None] - 1, 0, v - 1), axis=-1)
-    keep = (logits >= kth) | (top_k[:, None] <= 0)
-    return jnp.where(keep, logits, -jnp.inf)
+def _order_keys(x):
+    """int32 keys in the order of the float32 values `x`: sign-magnitude
+    bits to two's complement, so -0.0 and 0.0 share a key as they compare
+    equal (NaN is not ordered)."""
+    bits = lax.bitcast_convert_type(x, jnp.int32)
+    return jnp.where(bits < 0, -(bits & 0x7FFFFFFF), bits)
 
 
-def apply_top_p(logits, top_p):
-    """Nucleus filter: keep the smallest descending-probability prefix
-    whose cumulative mass reaches `top_p` (always at least the top-1;
-    ties at the threshold logit are all kept); `top_p >= 1` disables."""
-    sorted_desc = jnp.sort(logits, axis=-1)[:, ::-1]
-    probs = jax.nn.softmax(sorted_desc, axis=-1)
-    cum = jnp.cumsum(probs, axis=-1)
-    keep_sorted = (cum - probs) < top_p[:, None]
-    thresh = jnp.min(jnp.where(keep_sorted, sorted_desc, jnp.inf), axis=-1)
-    keep = (logits >= thresh[:, None]) | (top_p[:, None] >= 1.0)
-    return jnp.where(keep, logits, -jnp.inf)
+def _least_cut(values, holds):
+    """Per row, the least key c for which `holds(x, key(x) > c)` is true,
+    x = `values()` [S, V], `holds` monotone in c: bisection over
+    (min key - 1, max key], assumed false at the low end and true at the
+    high one.  What `holds` sums only changes at a value of the row, so
+    the cut is one.  Each pass recomputes x from the raw logits, so
+    nothing [S, V] is stored."""
+    def halve(_, bounds):
+        lo, hi = bounds
+        # floor((lo + hi) / 2) without the int32 overflow of lo + hi
+        mid = (lo >> 1) + (hi >> 1) + (lo & hi & 1)
+        x = values()
+        ok = holds(x, _order_keys(x) > mid[:, None])
+        return jnp.where(ok, lo, mid), jnp.where(ok, mid, hi)
+
+    x = values()
+    bounds = (_order_keys(jnp.min(x, axis=-1)) - 1,
+              _order_keys(jnp.max(x, axis=-1)))
+    return lax.fori_loop(0, _BISECT_STEPS, halve, bounds)[1]
+
+
+def _keep(x, cut):
+    """`x` where its key is at least the row's cut, else -inf."""
+    return jnp.where(_order_keys(x) >= cut[:, None], x, -jnp.inf)
+
+
+def _when_any(on, cut):
+    """`cut()` on the rows in `on`, run only if some row is; _NO_CUT on
+    the rest.  The barrier keeps XLA from sinking the [S, V] broadcast
+    that consumes the cut into the cond's branches."""
+    found = lambda: jnp.where(on, cut(), _NO_CUT)  # noqa: E731
+    none = lambda: jnp.full(on.shape, _NO_CUT)  # noqa: E731
+    return lax.optimization_barrier(lax.cond(jnp.any(on), found, none))
+
+
+def _cut(logits, temperature, top_p, top_k):
+    """Per-row key cut of scale -> top-k -> top-p.  The scaled (and
+    top-k-kept) rows are recomputed from `logits` wherever they are read,
+    never stored."""
+    scaled = lambda: scaled_logits(logits, temperature)  # noqa: E731
+    # top-k: the k-th highest value is the least key with fewer than k
+    # values above it (for k >= V that holds down to the bottom of the
+    # row, and every value is kept)
+    k_on = top_k > 0
+    k_cut = _when_any(k_on, lambda: _least_cut(
+        scaled, lambda x, above: jnp.sum(above, axis=-1) < top_k))
+    # top-p over the top-k survivors: the least value whose strictly-greater
+    # mass is below p, in the survivors' float32 softmax (max and sum taken
+    # once).  Near p = 1 the mass at or below the cut is the small, exact
+    # side of the comparison, so it is the side compared there.
+    kept = lambda: _keep(scaled(), k_cut)  # noqa: E731
+
+    def nucleus():
+        m = jnp.max(kept(), axis=-1, keepdims=True)
+        z = jnp.sum(jnp.exp(kept() - m), axis=-1)
+
+        def holds(x, above):
+            e = jnp.exp(x - m)
+            head = jnp.sum(jnp.where(above, e, 0.0), axis=-1)
+            tail = jnp.sum(jnp.where(above, 0.0, e), axis=-1)
+            return jnp.where(top_p > 0.5, tail > (1.0 - top_p) * z,
+                             head < top_p * z)
+
+        return _least_cut(kept, holds)
+
+    return jnp.maximum(k_cut, _when_any(top_p < 1.0, nucleus))
 
 
 def filter_logits(logits, temperature, top_p, top_k):
-    """The full pre-draw transform (scale -> top-k -> top-p), exposed so
-    the numpy-reference tests can compare kept-token sets without RNG."""
-    x = scaled_logits(logits, temperature)
-    x = apply_top_k(x, top_k)
-    return apply_top_p(x, top_p)
+    """The full pre-draw transform (scale -> top-k -> top-p, filtered
+    entries -inf), exposed so the numpy-reference tests can compare
+    kept-token sets without RNG.  Each filter applies to the rows whose
+    own parameter enables it."""
+    return _keep(scaled_logits(logits, temperature),
+                 _cut(logits, temperature, top_p, top_k))
 
 
 def sample_tokens(logits, temperature, top_p, top_k, keys):
     """Sample one token per row of `logits` [S, V].  Greedy rows
     (`temperature <= 0`) take argmax of the RAW logits; sampled rows draw
-    categorically from `filter_logits` under that row's own key."""
+    categorically from `filter_logits` under that row's own key.  A batch
+    with no sampled row skips the filters and the draw."""
     greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-    x = filter_logits(logits, temperature, top_p, top_k)
-    drawn = jax.vmap(lambda key, row: jax.random.categorical(key, row))(
-        keys, x)
-    return jnp.where(temperature > 0, drawn, greedy).astype(jnp.int32)
+    sampled, p_rows, k_rows = filter_rows(temperature, top_p, top_k)
+
+    def draw():
+        x = filter_logits(logits, temperature, jnp.where(p_rows, top_p, 1.0),
+                          jnp.where(k_rows, top_k, 0))
+        drawn = jax.vmap(lambda key, row: jax.random.categorical(key, row))(
+            keys, x)
+        return jnp.where(sampled, drawn, greedy).astype(jnp.int32)
+
+    return lax.cond(jnp.any(sampled), draw, lambda: greedy)

@@ -147,8 +147,9 @@ class _PackedLaunch:
     profile: heuristics.BatchProfile
     kcfg: heuristics.KernelConfig | None
     tokens: int  # launched token-bucket width
-    # per-row (temps, top_p, top_k, streams, num_generated) numpy arrays
-    # for the host-side `_sample_fn`; None on the fused path
+    # per-row (temps, top_p, top_k, streams, num_generated) numpy arrays:
+    # the fused launch's sampling inputs, the host-side `_sample_fn`'s, and
+    # what the sampler counters count
     sampling: tuple | None = None
     # speculative decoding: packed row index -> number of DRAFT tokens
     # verified in that row (the row emits 1..drafts+1 tokens); empty on
@@ -270,6 +271,10 @@ class Engine:
         self.spec_stats = {"proposed": 0, "accepted": 0, "emitted": 0,
                            "steps": 0}
         self._step_spec = (0, 0, 0)  # (proposed, accepted, emitted)/step
+        # cumulative sampler counters (per-step row counts land in packed
+        # step stats under "sampler"): packed launches that ran the draw,
+        # the top-p search and the top-k search
+        self.sampler_stats = {"draw": 0, "top_p": 0, "top_k": 0}
         self.seed = seed
         # mesh-aware launch layer: places params/cache and builds the
         # unified executables.  tp=1 degenerates to the pre-executor jit
@@ -545,6 +550,19 @@ class Engine:
             ngen[i] = r.num_generated + (1 if r._placeholder else 0)
         return temps, topp, topk, streams, ngen
 
+    def _count_sampler(self, pack: _PackedLaunch) -> dict:
+        """The launch's rows that draw and that need each search, by the
+        predicates the sampler's branches test (`filter_rows`); a branch
+        runs when its count is nonzero."""
+        temps, topp, topk, _, _ = pack.sampling
+        rows = M.sampling.filter_rows(temps, topp, topk)
+        sampled, top_p, top_k = (int(m.sum()) for m in rows)
+        self.sampler_stats["draw"] += int(sampled > 0)
+        self.sampler_stats["top_p"] += int(top_p > 0)
+        self.sampler_stats["top_k"] += int(top_k > 0)
+        return {"sampled_rows": sampled, "top_p_rows": top_p,
+                "top_k_rows": top_k}
+
     def _host_tokens(self, out, pack: _PackedLaunch):
         """Block on a unified launch's result and return host token ids:
         the fused path just transfers the sampled ids ([S], or
@@ -727,6 +745,7 @@ class Engine:
             stats["cache_alias_bytes"] = self.cache_alias_bytes[
                 self._unified_key(pack)]
             stats["kv_pool_bytes"] = self.kv_pool_bytes
+            stats["sampler"] = self._count_sampler(pack)
         if dec.prefill_reqs and self.prefix_cache is not None:
             with self._span("prefix_index", step):
                 for r in dec.prefill_reqs:
@@ -993,8 +1012,9 @@ class Engine:
             "slot_mapping": jnp.asarray(slots),
         }
         fill = [(i, r) for r, i, _ in rows]
+        pack.sampling = self._sampling_rows(s, fill)
         if self._fused:
-            temps, topp, topk, streams, ngen = self._sampling_rows(s, fill)
+            temps, topp, topk, streams, ngen = pack.sampling
             batch["temperature"] = jnp.asarray(temps)
             batch["top_p"] = jnp.asarray(topp)
             batch["top_k"] = jnp.asarray(topk)
@@ -1008,8 +1028,6 @@ class Engine:
                 for i, k in spec_map.items():
                     spec_lens[i] = k
                 batch["spec_lens"] = jnp.asarray(spec_lens)
-        else:
-            pack.sampling = self._sampling_rows(s, fill)
         return batch, pack
 
     def _unified_key(self, pack: _PackedLaunch) -> tuple:

@@ -129,6 +129,12 @@ def assert_step_invariants(eng: Engine, stats: dict) -> None:
     if "cache_alias_bytes" in stats:
         assert stats["kv_pool_bytes"] == eng.kv_pool_bytes > 0, stats
         assert stats["cache_alias_bytes"] >= eng.kv_pool_bytes, stats
+    # the sampler counts rows of this launch: a search's rows draw, and
+    # only scheduled requests draw
+    if "sampler" in stats:
+        smp = stats["sampler"]
+        assert max(smp["top_p_rows"], smp["top_k_rows"]) \
+            <= smp["sampled_rows"] <= stats["decode"] + stats["prefill"], smp
 
 
 def model_launches(eng: Engine) -> int:

@@ -193,21 +193,114 @@ def _numpy_filter(logits, temperature, top_p, top_k):
     return np.isfinite(x)
 
 
-def test_top_k_top_p_match_numpy_reference():
+def _kept_case(name):
+    """(logits [S, V], temperature, top_p, top_k) of one kept-set case."""
     rng = np.random.default_rng(7)
-    logits = rng.normal(size=(6, 32)).astype(np.float32) * 3
-    temperature = np.array([0.0, 0.5, 1.0, 0.7, 1.3, 1.0], np.float32)
-    top_p = np.array([1.0, 0.9, 0.5, 1.0, 0.3, 0.999], np.float32)
-    top_k = np.array([0, 5, 0, 3, 8, 1], np.int32)
+    f32, i32 = np.float32, np.int32
+    if name == "small":
+        return (rng.normal(size=(6, 32)).astype(f32) * 3,
+                np.array([0.0, 0.5, 1.0, 0.7, 1.3, 1.0], f32),
+                np.array([1.0, 0.9, 0.5, 1.0, 0.3, 0.999], f32),
+                np.array([0, 5, 0, 3, 8, 1], i32))
+    if name == "qwen-vocab":  # a nucleus of tens of thousands of tokens
+        return (rng.normal(size=(2, 151936)).astype(f32) * 2,
+                np.array([0.7, 1.0], f32), np.array([0.9, 0.5], f32),
+                np.zeros(2, i32))
+    if name == "ties":  # few distinct values: the cuts fall inside ties
+        logits = rng.integers(-3, 4, size=(5, 64)).astype(f32)
+        return (logits, np.ones(5, f32),
+                np.array([0.3, 0.6, 0.95, 1.0, 0.5], f32),
+                np.array([0, 0, 0, 7, 20], i32))
+    if name == "tiny-top-p":  # keeps only the top-1
+        return (rng.normal(size=(3, 256)).astype(f32),
+                np.array([0.5, 1.0, 2.0], f32), np.full(3, 1e-4, f32),
+                np.zeros(3, i32))
+    if name == "top-k-over-vocab":
+        return (rng.normal(size=(3, 48)).astype(f32),
+                np.ones(3, f32), np.array([1.0, 0.8, 1.0], f32),
+                np.array([48, 49, 1000], i32))
+    if name == "neg-inf":  # masked entries, some rows with few finite ones
+        logits = rng.normal(size=(4, 40)).astype(f32) * 2
+        logits[:, ::3] = -np.inf
+        logits[3, 5:] = -np.inf
+        return (logits, np.array([1.0, 0.8, 1.0, 1.0], f32),
+                np.array([0.9, 1.0, 0.7, 0.99], f32),
+                np.array([0, 30, 25, 3], i32))
+    if name == "all-greedy":  # unscaled rows, filters as each row sets
+        return (rng.normal(size=(4, 32)).astype(f32) * 3,
+                np.zeros(4, f32), np.array([1.0, 0.9, 0.5, 1.0], f32),
+                np.array([0, 0, 4, 6], i32))
+    assert name == "all-disabled"
+    return (rng.normal(size=(4, 32)).astype(f32) * 3,
+            np.array([0.0, 0.5, 1.0, 1.5], f32), np.ones(4, f32),
+            np.zeros(4, i32))
+
+
+KEPT_CASES = ["small", "qwen-vocab", "ties", "tiny-top-p",
+              "top-k-over-vocab", "neg-inf", "all-greedy", "all-disabled"]
+
+
+@pytest.mark.parametrize("case", KEPT_CASES)
+def test_top_k_top_p_match_numpy_reference(case):
+    """The bisected cuts keep exactly the float64 sort definition's set."""
+    logits, temperature, top_p, top_k = _kept_case(case)
     got = np.isfinite(np.asarray(sampling.filter_logits(
         logits, temperature, top_p, top_k)))
     want = _numpy_filter(logits, temperature, top_p, top_k)
     np.testing.assert_array_equal(got, want)
-    # disabled filters keep everything
-    all_kept = np.isfinite(np.asarray(sampling.filter_logits(
-        logits, np.ones(6, np.float32), np.ones(6, np.float32),
-        np.zeros(6, np.int32))))
-    assert all_kept.all()
+    if case == "tiny-top-p":
+        assert (want.sum(axis=1) == 1).all()
+    if case == "all-disabled":
+        assert want.all()
+
+
+def test_sampled_tokens_unchanged_and_counted(cfg_params, prompts):
+    """`sample_tokens` draws what `jax.random.categorical` draws from the
+    numpy reference's kept sets under the same keys (greedy rows: the raw
+    argmax), and packed `step()["sampler"]` counts the rows that draw and
+    that need each search, on a mixed and on an all-greedy batch."""
+    rng = np.random.default_rng(3)
+    logits = rng.normal(size=(6, 64)).astype(np.float32) * 3
+    temperature = np.array([0.0, 0.7, 1.0, 0.8, 1.2, 0.0], np.float32)
+    top_p = np.array([0.9, 0.9, 1.0, 0.5, 1.0, 1.0], np.float32)
+    top_k = np.array([5, 0, 6, 10, 0, 0], np.int32)
+    keys = sampling.request_keys(5, np.arange(6, dtype=np.int32),
+                                 np.full(6, 2, np.int32))
+    for temps in (temperature, np.zeros(6, np.float32)):
+        kept = _numpy_filter(logits, temps, top_p, top_k)
+        x = np.where(kept, np.asarray(sampling.scaled_logits(logits, temps)),
+                     -np.inf)
+        drawn = np.asarray(jax.vmap(jax.random.categorical)(keys, x))
+        want = np.where(temps > 0, drawn, np.argmax(logits, axis=-1))
+        got = np.asarray(sampling.sample_tokens(logits, temps, top_p, top_k,
+                                                keys))
+        np.testing.assert_array_equal(got, want)
+
+    cfg, params = cfg_params
+    # a greedy row's own filters never count: it does not draw
+    mixed = [dict(top_p=0.5, top_k=3), dict(temperature=0.8, top_p=0.9),
+             dict(temperature=1.0, top_k=5),
+             dict(temperature=0.7, top_p=0.5, top_k=10)]
+    for kinds, engaged in ((mixed, True), ([{}] * 4, False)):
+        eng = build_engine(cfg, params)
+        reqs = [Request(prompt=list(p), max_new_tokens=MAX_NEW, seed=i, **kw)
+                for i, (p, kw) in enumerate(zip(prompts, kinds))]
+        for r in reqs:
+            eng.add_request(r)
+        ran = {"draw": 0, "top_p": 0, "top_k": 0}
+        while eng.sched.has_work:
+            before = [r.num_generated for r in reqs]
+            st = eng.step()["sampler"]
+            drew = [r for r, n in zip(reqs, before) if r.num_generated > n]
+            sampled = [r for r in drew if r.temperature > 0]
+            assert st == {"sampled_rows": len(sampled),
+                          "top_p_rows": sum(r.top_p < 1 for r in sampled),
+                          "top_k_rows": sum(r.top_k > 0 for r in sampled)}
+            for k in ("draw", "top_p", "top_k"):
+                n = st["sampled_rows" if k == "draw" else f"{k}_rows"]
+                ran[k] += int(n > 0)
+        assert eng.sampler_stats == ran
+        assert all(ran.values()) if engaged else not any(ran.values())
 
 
 def test_request_keys_counter_stream():

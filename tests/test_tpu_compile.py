@@ -227,10 +227,9 @@ def test_unified_step_updates_pool_in_place(one_chip, monkeypatch, arch):
     the loop plumbing yields a result as large as one layer's K pool (no
     slice, pad, relayout or rewrite of a layer), the compile aliases the
     whole pool to the output, and it needs less scratch than one layer's
-    K pool.  The fused sampler is left out: it never touches the pool, and
-    its [S, V] sort and noise buffers (124 MB for Qwen, 203 MB for SmolLM)
-    would swamp the scratch bound; `test_qwen_unified_step_compiles_for_v5e`
-    compiles the step with it."""
+    K pool.  The fused sampler is left out: it never touches the pool;
+    `test_sampler_has_no_sort` bounds its scratch alone, and
+    `test_qwen_unified_step_compiles_for_v5e` compiles the step with it."""
     monkeypatch.setattr(repro.kernels, "interpret_mode",
                         lambda interpret=None: False)
     max_seqs, pages, max_len, kcfg = CELLS[arch]
@@ -262,3 +261,23 @@ def test_unified_step_updates_pool_in_place(one_chip, monkeypatch, arch):
     ma = compiled.memory_analysis()
     assert ma.alias_size_in_bytes >= pool_bytes, ma
     assert ma.temp_size_in_bytes < layer_k_bytes, ma
+
+
+@pytest.mark.parametrize("arch", sorted(CELLS))
+def test_sampler_has_no_sort(one_chip, arch):
+    """The fused sampler alone at a benchmark cell's `[2 x max_seqs, V]`
+    float32 logits: no `sort` instruction (its top-k and top-p cuts are
+    bisected), and less scratch than one `[S, V]` float32 array, where the
+    sorting sampler needed 84 MB (Qwen) and 102 MB (SmolLM): the filtered
+    rows are recomputed from the logits in each pass, never stored."""
+    s, v = 2 * CELLS[arch][0], ARCHS[arch].vocab_size
+    sh = one_chip
+    f32, i32 = jnp.float32, jnp.int32
+    keys = jax.ShapeDtypeStruct((s,), jax.random.key(0).dtype, sharding=sh)
+    compiled = jax.jit(M.sampling.sample_tokens).lower(
+        _spec(sh, (s, v), f32), _spec(sh, (s,), f32), _spec(sh, (s,), f32),
+        _spec(sh, (s,), i32), keys).compile()
+    sorts = re.findall(r"^.*\bsort\(.*$", compiled.as_text(), re.M)
+    assert not sorts, "\n".join(line.strip()[:200] for line in sorts)
+    ma = compiled.memory_analysis()
+    assert ma.temp_size_in_bytes < s * v * 4, ma
